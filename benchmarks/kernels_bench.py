@@ -93,7 +93,7 @@ def _autotune(rng):
     """Tuned vs default block sizes (winners persisted on disk)."""
     x = jnp.asarray(rng.standard_normal((3000, 256)), jnp.float32)
     sc = jnp.asarray(rng.standard_normal((256,)), jnp.float32)
-    cfg = at.tune_rmsnorm(x, sc, eps=1e-5, interpret=True)
+    cfg = at.tune_rmsnorm(x, sc, eps=1e-5)
     t_def = _time(lambda: kops.rmsnorm(x, sc, block_rows=256))
     t_tuned = _time(lambda: kops.rmsnorm(x, sc,
                                          block_rows=cfg["block_rows"]))
@@ -102,7 +102,7 @@ def _autotune(rng):
     q = jnp.asarray(rng.standard_normal((4, 512, 64)), jnp.float32)
     k = jnp.asarray(rng.standard_normal((4, 512, 64)), jnp.float32)
     v = jnp.asarray(rng.standard_normal((4, 512, 64)), jnp.float32)
-    fcfg = at.tune_flash_attention(q, k, v, causal=True, interpret=True)
+    fcfg = at.tune_flash_attention(q, k, v, causal=True)
     from repro.kernels import flash_attention as fa
     t_def = at.bench_time(lambda: fa.flash_attention(
         q, k, v, causal=True, interpret=True), iters=5)

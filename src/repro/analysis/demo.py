@@ -1,7 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-# ^ MUST precede any jax import: device count locks at first backend init.
-
 """End-to-end collective-audit demo on a 2-zone mesh (CI's audit gate).
 
 Compiles a small scan-over-layers training step on a (pod=2, data=2,
@@ -30,6 +26,7 @@ variant produces at least one error finding.
 """
 import argparse
 import json
+import os
 import sys
 from typing import Dict, Tuple
 
@@ -129,6 +126,8 @@ def main(argv=None) -> int:
                     choices=["clean", "seeded", "both"])
     ap.add_argument("--out", default="artifacts/analysis")
     args = ap.parse_args(argv)
+    # eight host devices; takes effect only before JAX's first backend use
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     ok = True
     if args.variant in ("clean", "both"):
         clean = audit_variant(True, args.out)

@@ -175,6 +175,23 @@ def kernel_table_path(chip: str) -> "os.PathLike":
     return root / f"kernel-costs-{chip}.json"
 
 
+# ``jax.Device.device_kind`` -> ACCELERATORS key, for devices this repo has
+# run on.  A kind missing here is an error, never a default peak.
+DEVICE_KINDS: Dict[str, str] = {
+    "TPU v5 lite": "tpu-v5e",
+    "cpu": "cpu-host",
+}
+
+
+def accelerator_for_kind(device_kind: str) -> str:
+    """The ACCELERATORS key of a device as JAX reports its kind."""
+    try:
+        return DEVICE_KINDS[device_kind]
+    except KeyError:
+        raise KeyError(f"unknown device_kind {device_kind!r}; known: "
+                       f"{sorted(DEVICE_KINDS)}") from None
+
+
 def get_accelerator(name: str) -> AcceleratorSpec:
     try:
         return ACCELERATORS[name]

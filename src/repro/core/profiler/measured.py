@@ -345,7 +345,7 @@ def calibrate_kernels(chip: Optional[str] = None, *,
                       path: Optional[str] = None) -> KernelCalibration:
     """Benchmark the real Pallas kernels into a per-(op, shape, dtype,
     chip) cost table (interpret mode on this CPU container; Mosaic on a
-    real TPU — same code path, ``ops._interpret()`` decides).
+    real TPU — same code path, ``ops.on_platform`` decides).
 
     With ``autotune_blocks`` the autotuner picks the tiling first (winner
     cached on disk), so the table prices the *tuned* kernels.  The table

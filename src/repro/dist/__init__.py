@@ -1,9 +1,3 @@
 """Distributed-execution layer: sharding policy engine, mesh factory,
-heterogeneous MPMD pipeline (paper §4.4).
-
-Importing this package installs the jax compatibility shims (see
-``repro.dist.compat``) so every consumer — models, train, serve, launch —
-gets a uniform API surface regardless of the pinned jax version.
-"""
-from repro.dist import compat  # noqa: F401  (side effect: install shims)
+heterogeneous MPMD pipeline (paper §4.4)."""
 from repro.dist import mesh, sharding  # noqa: F401

@@ -8,7 +8,9 @@ All meshes in the repo use the same axis vocabulary (DESIGN.md §4):
 Helpers take explicit sizes so planner output (dp, tp[, pods]) maps 1:1
 onto a mesh; devices default to ``jax.devices()`` prefix order, which is
 also the contract the MPMD pipeline uses to carve disjoint per-stage
-device sets.
+device sets.  Every axis is ``AxisType.Auto``: the sharding code relies on
+XLA's propagation, and ``jax.make_mesh`` would otherwise make the axes
+Explicit.
 """
 from __future__ import annotations
 
@@ -17,9 +19,7 @@ from typing import Optional, Sequence, Tuple
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
-
-from repro.dist import compat  # noqa: F401  (installs jax API shims)
+from jax.sharding import AxisType, Mesh
 
 
 def named_mesh(shape: Sequence[int], axes: Sequence[str],
@@ -37,7 +37,9 @@ def named_mesh(shape: Sequence[int], axes: Sequence[str],
     if len(devices) != n:
         raise ValueError(f"need {n} devices for mesh {tuple(shape)}, "
                          f"got {len(devices)}")
-    return jax.make_mesh(tuple(shape), tuple(axes), devices=devices)
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def data_model_mesh(dp: int, tp: int,

@@ -34,6 +34,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.dist import mesh as mesh_lib
 from repro.dist import sharding as shd
+from repro.launch.compile_cache import persistent_cache_disabled
 from repro.models import layers as L
 from repro.models import transformer
 from repro.models.config import ModelConfig
@@ -110,12 +111,15 @@ def _slice_full_params(full: Any, stage: Stage) -> Dict[str, Any]:
 
 
 def _stage_apply(cfg: ModelConfig, stage: Stage, params, x):
-    """Stage forward: tokens (first) or hidden states -> hidden states."""
+    """Stage forward: tokens (first) or hidden states -> hidden states.
+
+    Differentiated: the backward programs re-run it under ``jax.vjp``, and
+    the forward program must match them numerically."""
     if stage.first:
         x = params["embed"][x].astype(cfg.dtype)
     s = x.shape[1]
     positions = jnp.arange(s)
-    impl = L.pick_attn_impl(cfg.attn_impl, s)
+    impl = L.pick_attn_impl(cfg.attn_impl, s, differentiated=True)
 
     def body(h, lp):
         h, _ = transformer.attn_block(cfg, lp, h, positions, impl, None)
@@ -140,6 +144,22 @@ def _stage_loss(cfg: ModelConfig, stage: Stage, params, x, labels):
     logits = (h @ params["lm_head"].astype(cfg.dtype)).astype(jnp.float32)
     nll_sum, n_tok, _ = masked_ce_sums(logits, labels)
     return nll_sum / jnp.maximum(n_tok, 1)
+
+
+def _uncached(fn):
+    """``fn``, compiled and run outside the persistent compile cache.
+
+    On TPU (JAX 0.9.0, libtpu 0.0.34) a multi-chip executable whose devices
+    do not start at device 0 halts the chips when it is loaded back from
+    the cache: the runtime's launch barrier fails (``Invalid logical z``).
+    Compiled afresh, the same program runs.  Every stage after the first
+    has such a device set, so no stage program goes through the cache.
+    """
+    @functools.wraps(fn)
+    def call(*args):
+        with persistent_cache_disabled():
+            return fn(*args)
+    return call
 
 
 class MPMDPipeline:
@@ -298,7 +318,7 @@ class MPMDPipeline:
             prog["bwd"] = jax.jit(bwd_first)
         else:
             prog["bwd"] = jax.jit(bwd_mid)
-        return prog
+        return {name: _uncached(fn) for name, fn in prog.items()}
 
     # --- parameter loading -----------------------------------------------------
 
@@ -318,7 +338,7 @@ class MPMDPipeline:
             p = jax.device_put(sliced, ps)
             self.params.append(p)
             self.opt_states.append(
-                jax.jit(opt_lib.init_state, out_shardings=os_)(p))
+                _uncached(jax.jit(opt_lib.init_state, out_shardings=os_))(p))
         return full
 
     def init_params(self, key: jax.Array) -> None:
@@ -328,13 +348,13 @@ class MPMDPipeline:
         keys = jax.random.split(key, len(self.stages))
         for st, k, ps, os_ in zip(self.stages, keys, self._pshards,
                                   self._oshards):
-            p = jax.jit(
+            p = _uncached(jax.jit(
                 lambda kk, st=st: shd.init_from_decls(
                     stage_decls(self.cfg, st), kk, self.cfg.param_dtype),
-                out_shardings=ps)(k)
+                out_shardings=ps))(k)
             self.params.append(p)
             self.opt_states.append(
-                jax.jit(opt_lib.init_state, out_shardings=os_)(p))
+                _uncached(jax.jit(opt_lib.init_state, out_shardings=os_))(p))
 
     # --- transfers -------------------------------------------------------------
 

@@ -32,8 +32,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.dist import compat
-
 Axis = Optional[str]
 Rules = Mapping[str, Tuple[str, ...]]
 
@@ -165,7 +163,8 @@ def _sanitize(shape: Sequence[int], spec: P, sizes: Dict[str, int]) -> P:
 
 
 def constrain(x: jax.Array, spec: P) -> jax.Array:
-    """``with_sharding_constraint`` against the context mesh, or no-op.
+    """``with_sharding_constraint`` against the ``jax.set_mesh`` mesh, or
+    no-op.
 
     The spec is sanitized with the same divisibility/axis-once rules as
     ``logical_to_spec`` so callers can pass optimistic hints (e.g. heads
@@ -174,8 +173,8 @@ def constrain(x: jax.Array, spec: P) -> jax.Array:
     single-device paths free of partitioner machinery.  Under ``vmap`` the
     constraint sees the unbatched aval and JAX prepends the batch dim.
     """
-    mesh = compat.context_mesh()
-    if mesh is None:
+    mesh = jax.sharding.get_abstract_mesh()     # what jit sees of set_mesh
+    if mesh.empty:
         return x
     spec = _sanitize(x.shape, spec, _mesh_sizes(mesh))
     if all(s is None for s in tuple(spec)):

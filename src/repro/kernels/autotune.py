@@ -39,10 +39,11 @@ Config = Dict[str, int]
 
 
 def default_chip() -> str:
-    """Cache identity of the device the kernels actually run on."""
-    if jax.default_backend() == "tpu":     # pragma: no cover (no TPU here)
-        return jax.devices()[0].device_kind.replace(" ", "-").lower()
-    return "cpu-host"
+    """Cache identity of the device the kernels actually run on: its
+    ``hw_specs.ACCELERATORS`` key, so calibrated tables are filed under
+    the name the profiler asks for."""
+    from repro.core.profiler.hw_specs import accelerator_for_kind
+    return accelerator_for_kind(jax.devices()[0].device_kind)
 
 
 def enabled() -> bool:
@@ -164,59 +165,63 @@ def chunk_candidates(s: int) -> List[Config]:
     return [{"chunk": c} for c in sorted({min(c, s) for c in (64, 128, 256)})]
 
 
-def tune_flash_attention(q, k, v, *, causal: bool, interpret: bool,
+def tune_flash_attention(q, k, v, *, causal: bool,
                          cache: Optional[AutotuneCache] = None) -> Config:
     from repro.kernels import flash_attention as fa
+    from repro.kernels.ops import on_platform
     bh, sq, d = q.shape
     sk = k.shape[1]
 
     def bench(c: Config) -> float:
-        return bench_time(lambda: fa.flash_attention(
-            q, k, v, causal=causal, interpret=interpret, **c))
+        return bench_time(lambda: on_platform(
+            fa.flash_attention, q, k, v, causal=causal, **c))
 
     return autotune("flash_attention", (bh, sq, sk, d, int(causal)),
                     str(q.dtype), flash_candidates(sq, sk), bench,
                     cache=cache)
 
 
-def tune_rmsnorm(x, scale, *, eps: float, interpret: bool,
+def tune_rmsnorm(x, scale, *, eps: float,
                  cache: Optional[AutotuneCache] = None) -> Config:
     from repro.kernels import rmsnorm as rn
+    from repro.kernels.ops import on_platform
     rows = 1
     for s in x.shape[:-1]:
         rows *= s
 
     def bench(c: Config) -> float:
-        return bench_time(lambda: rn.rmsnorm(
-            x, scale, eps=eps, interpret=interpret, **c))
+        return bench_time(lambda: on_platform(
+            rn.rmsnorm, x, scale, eps=eps, **c))
 
     return autotune("rmsnorm", (rows, x.shape[-1]), str(x.dtype),
                     rows_candidates(rows), bench, cache=cache)
 
 
-def tune_fused_add_rmsnorm(x, res, scale, *, eps: float, interpret: bool,
+def tune_fused_add_rmsnorm(x, res, scale, *, eps: float,
                            cache: Optional[AutotuneCache] = None) -> Config:
     from repro.kernels import fused
+    from repro.kernels.ops import on_platform
     rows = 1
     for s in x.shape[:-1]:
         rows *= s
 
     def bench(c: Config) -> float:
-        return bench_time(lambda: fused.fused_add_rmsnorm(
-            x, res, scale, eps=eps, interpret=interpret, **c))
+        return bench_time(lambda: on_platform(
+            fused.fused_add_rmsnorm, x, res, scale, eps=eps, **c))
 
     return autotune("fused_add_rmsnorm", (rows, x.shape[-1]), str(x.dtype),
                     rows_candidates(rows), bench, cache=cache)
 
 
-def tune_ssd_scan(x, dt, a, b, c, *, interpret: bool,
+def tune_ssd_scan(x, dt, a, b, c, *,
                   cache: Optional[AutotuneCache] = None) -> Config:
     from repro.kernels import ssd as ssd_mod
+    from repro.kernels.ops import on_platform
     bs, s, h, p = x.shape
 
     def bench(cand: Config) -> float:
-        return bench_time(lambda: ssd_mod.ssd_scan(
-            x, dt, a, b, c, interpret=interpret, **cand))
+        return bench_time(lambda: on_platform(
+            ssd_mod.ssd_scan, x, dt, a, b, c, **cand))
 
     return autotune("ssd_scan", (bs, s, h, p, b.shape[-1]), str(x.dtype),
                     chunk_candidates(s), bench, cache=cache)

@@ -25,9 +25,11 @@ with S = Q K^T / sqrt(d) in fp32.
 
 ``flash_attention_decode`` is the serving-shaped variant: q_len == 1
 against a long KV cache with a *dynamic* valid length.  The q row stays
-resident in VMEM while the grid sweeps KV blocks; blocks past the cache
-length are skipped at runtime (predicated), so decode cost tracks the
-actual cache fill, not the allocated ring size.
+resident in VMEM while the grid sweeps KV blocks.  The length is a
+scalar-prefetch operand (SMEM): blocks past it are skipped at runtime, and
+their index map clamps to the last live block, so no DMA is issued for
+them either — decode cost tracks the actual cache fill, not the allocated
+ring size.
 """
 from __future__ import annotations
 
@@ -39,11 +41,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:                                     # TPU scratch namespace
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:                        # pragma: no cover
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -137,11 +135,9 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         _kernel, block_q=block_q, block_k=block_k, causal=causal,
         scale=scale, n_kv_blocks=nk, kv_len=sk if pad_k else None)
     scratch = [
-        _VMEM((block_q, d), jnp.float32),
-        _VMEM((block_q,), jnp.float32),
-        _VMEM((block_q,), jnp.float32),
-    ] if _VMEM is not None else [
-        pl.MemorySpace.ANY,  # pragma: no cover (non-TPU build)
+        pltpu.VMEM((block_q, d), jnp.float32),
+        pltpu.VMEM((block_q,), jnp.float32),
+        pltpu.VMEM((block_q,), jnp.float32),
     ]
     out = pl.pallas_call(
         kern,
@@ -173,7 +169,7 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
 
     def _body():
-        q = q_ref[...].astype(jnp.float32)          # (1, d)
+        q = q_ref[0].astype(jnp.float32)            # (1, d)
         k = k_ref[0].astype(jnp.float32)            # (bk, d)
         v = v_ref[0].astype(jnp.float32)
         s = jax.lax.dot_general(
@@ -182,12 +178,12 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
         k_pos = ki * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (1, k.shape[0]), 1)
         s = jnp.where(k_pos >= kv_len, NEG_INF, s)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=1))
+        m_prev = m_ref[...]                          # (1, 1)
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
         corr = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None])
-        l_ref[...] = l_ref[...] * corr + p.sum(axis=1)
-        acc_ref[...] = (acc_ref[...] * corr[:, None]
+        p = jnp.exp(s - m_new)
+        l_ref[...] = l_ref[...] * corr + p.sum(axis=1, keepdims=True)
+        acc_ref[...] = (acc_ref[...] * corr
                         + jax.lax.dot_general(
                             p, v, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32))
@@ -198,9 +194,8 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
 
     @pl.when(ki == n_kv_blocks - 1)
     def _finalize():
-        l = l_ref[...]
-        o_ref[...] = (acc_ref[...] / jnp.maximum(l, 1e-30)[:, None]
-                      ).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+                    ).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("block_k", "interpret"))
@@ -212,6 +207,8 @@ def flash_attention_decode(q: jax.Array, k: jax.Array, v: jax.Array,
     The kernel scales the Q row by 1/sqrt(d) once up front (cheaper than
     rescaling every score block).  S is padded to the block grid; both the
     pad and positions >= ``kv_len`` are masked via the same comparison.
+    Q and the output travel as (BH, 1, D) so each block's trailing dims
+    are the array's own, as the TPU tiling rule requires.
     """
     bh, d = q.shape
     sk = k.shape[1]
@@ -220,28 +217,33 @@ def flash_attention_decode(q: jax.Array, k: jax.Array, v: jax.Array,
     k = _pad_axis1(k, pad_k)
     v = _pad_axis1(v, pad_k)
     nk = (sk + pad_k) // block_k
-    q = (q.astype(jnp.float32) / math.sqrt(d)).astype(q.dtype)
+    q = (q.astype(jnp.float32) / math.sqrt(d)).astype(q.dtype)[:, None]
     kv_len = jnp.asarray(kv_len, jnp.int32).reshape(1)
 
+    def kv_block(b, j, len_ref):
+        # clamp to the last live block: a repeated block index issues no DMA
+        last = jnp.maximum(len_ref[0] - 1, 0) // block_k
+        return (b, jnp.minimum(j, last), 0)
+
     kern = functools.partial(_decode_kernel, block_k=block_k, n_kv_blocks=nk)
-    scratch = [
-        _VMEM((1, d), jnp.float32),
-        _VMEM((1,), jnp.float32),
-        _VMEM((1,), jnp.float32),
-    ] if _VMEM is not None else [
-        pl.MemorySpace.ANY,  # pragma: no cover (non-TPU build)
-    ]
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kern,
-        grid=(bh, nk),
-        in_specs=[
-            pl.BlockSpec((1,), lambda b, j: (0,)),
-            pl.BlockSpec((1, d), lambda b, j: (b, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j: (b, j, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, d), lambda b, j: (b, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, d), q.dtype),
-        scratch_shapes=scratch,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(bh, nk),
+            in_specs=[
+                pl.BlockSpec((1, 1, d), lambda b, j, len_ref: (b, 0, 0)),
+                pl.BlockSpec((1, block_k, d), kv_block),
+                pl.BlockSpec((1, block_k, d), kv_block),
+            ],
+            out_specs=pl.BlockSpec((1, 1, d),
+                                   lambda b, j, len_ref: (b, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((1, d), jnp.float32),
+                pltpu.VMEM((1, 1), jnp.float32),
+                pltpu.VMEM((1, 1), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((bh, 1, d), q.dtype),
         interpret=interpret,
     )(kv_len, q, k, v)
+    return out[:, 0]

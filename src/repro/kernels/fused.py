@@ -21,11 +21,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:                         # pragma: no cover
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _kernel(x_ref, r_ref, s_ref, y_ref, o_ref, scale_ref, *, eps: float):
@@ -42,8 +38,6 @@ def _kernel(x_ref, r_ref, s_ref, y_ref, o_ref, scale_ref, *, eps: float):
 
 def _fused_rows(xf, rf, scale, eps: float, br: int, interpret: bool):
     rows, d = xf.shape
-    scratch = ([_VMEM((d,), jnp.float32)] if _VMEM is not None
-               else [pl.MemorySpace.ANY])  # pragma: no cover (non-TPU)
     return pl.pallas_call(
         functools.partial(_kernel, eps=eps),
         grid=(rows // br,),
@@ -60,7 +54,7 @@ def _fused_rows(xf, rf, scale, eps: float, br: int, interpret: bool):
             jax.ShapeDtypeStruct(xf.shape, xf.dtype),
             jax.ShapeDtypeStruct(xf.shape, xf.dtype),
         ],
-        scratch_shapes=scratch,
+        scratch_shapes=[pltpu.VMEM((d,), jnp.float32)],
         interpret=interpret,
     )(xf, rf, scale)
 

@@ -1,11 +1,11 @@
 """Jit'd public wrappers around the Pallas kernels.
 
 Handles layout adaptation (model tensors are (B, S, H, D); kernels take
-flattened (B*H, S, D)), GQA head replication, and the interpret-mode
-fallback: on a CPU backend (this container) kernels execute via
-``interpret=True``, which runs the same kernel body under the Pallas
-interpreter — numerics identical, used by tests; on TPU they compile to
-Mosaic.
+flattened (B*H, S, D)), GQA head replication, and where a kernel runs:
+``on_platform`` compiles it to Mosaic when the program is lowered for a
+TPU and runs the same body under the Pallas interpreter elsewhere (CPU
+tests).  The choice follows the platform the program is lowered for, not
+the process's default backend, so a kernel is never interpreted on a TPU.
 
 Block sizes: passing explicit ints pins the tiling; ``None`` (default)
 uses the MXU-aligned defaults, or — when autotuning is on (the
@@ -15,7 +15,8 @@ cache.
 """
 from __future__ import annotations
 
-from typing import Optional, Tuple, Union
+import functools
+from typing import Callable, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -29,8 +30,13 @@ from repro.kernels import ssd as ssd_mod
 BlockArg = Union[int, str, None]          # int | "auto" | None
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+def on_platform(kernel: Callable, *args, **static):
+    """``kernel(*args, **static)`` as Mosaic on a TPU, interpreted
+    elsewhere; ``kernel`` takes an ``interpret`` keyword."""
+    return jax.lax.platform_dependent(
+        *args,
+        tpu=functools.partial(kernel, interpret=False, **static),
+        default=functools.partial(kernel, interpret=True, **static))
 
 
 def _tune(block: BlockArg) -> bool:
@@ -52,13 +58,12 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     kt = k.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
     vt = v.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
     if _tune(block_q) or _tune(block_k):
-        cfg = at.tune_flash_attention(qt, kt, vt, causal=causal,
-                                      interpret=_interpret())
+        cfg = at.tune_flash_attention(qt, kt, vt, causal=causal)
         block_q, block_k = cfg["block_q"], cfg["block_k"]
     bq = block_q if isinstance(block_q, int) else 128
     bk = block_k if isinstance(block_k, int) else 128
-    o = fa.flash_attention(qt, kt, vt, causal=causal, block_q=bq,
-                           block_k=bk, interpret=_interpret())
+    o = on_platform(fa.flash_attention, qt, kt, vt, causal=causal,
+                    block_q=bq, block_k=bk)
     return o.reshape(b, h, s, d).transpose(0, 2, 1, 3)
 
 
@@ -79,27 +84,23 @@ def flash_attention_decode(q: jax.Array, k: jax.Array, v: jax.Array, *,
     kt = k.transpose(0, 2, 1, 3).reshape(b * h, s, d)
     vt = v.transpose(0, 2, 1, 3).reshape(b * h, s, d)
     bk = block_k if isinstance(block_k, int) else 128
-    o = fa.flash_attention_decode(qt, kt, vt, cache_len, block_k=bk,
-                                  interpret=_interpret())
+    o = on_platform(fa.flash_attention_decode, qt, kt, vt, cache_len,
+                    block_k=bk)
     return o.reshape(b, h, d)[:, None].reshape(b, 1, h, d)
 
 
 def ssd_scan(x, dt, a, b, c, *, chunk: BlockArg = None):
     if _tune(chunk):
-        chunk = at.tune_ssd_scan(x, dt, a, b, c,
-                                 interpret=_interpret())["chunk"]
+        chunk = at.tune_ssd_scan(x, dt, a, b, c)["chunk"]
     ck = chunk if isinstance(chunk, int) else 128
-    return ssd_mod.ssd_scan(x, dt, a, b, c, chunk=ck,
-                            interpret=_interpret())
+    return on_platform(ssd_mod.ssd_scan, x, dt, a, b, c, chunk=ck)
 
 
 def rmsnorm(x, scale, *, eps: float = 1e-5, block_rows: BlockArg = None):
     if _tune(block_rows):
-        block_rows = at.tune_rmsnorm(x, scale, eps=eps,
-                                     interpret=_interpret())["block_rows"]
+        block_rows = at.tune_rmsnorm(x, scale, eps=eps)["block_rows"]
     br = block_rows if isinstance(block_rows, int) else 256
-    return rn.rmsnorm(x, scale, eps=eps, block_rows=br,
-                      interpret=_interpret())
+    return on_platform(rn.rmsnorm, x, scale, eps=eps, block_rows=br)
 
 
 def fused_add_rmsnorm(x, res, scale, *, eps: float = 1e-5,
@@ -108,9 +109,7 @@ def fused_add_rmsnorm(x, res, scale, *, eps: float = 1e-5,
     """Returns (rmsnorm(x + res) * scale, x + res) in one HBM pass."""
     if _tune(block_rows):
         block_rows = at.tune_fused_add_rmsnorm(
-            x, res, scale, eps=eps,
-            interpret=_interpret())["block_rows"]
+            x, res, scale, eps=eps)["block_rows"]
     br = block_rows if isinstance(block_rows, int) else 256
-    return fused_mod.fused_add_rmsnorm(x, res, scale, eps=eps,
-                                       block_rows=br,
-                                       interpret=_interpret())
+    return on_platform(fused_mod.fused_add_rmsnorm, x, res, scale, eps=eps,
+                       block_rows=br)

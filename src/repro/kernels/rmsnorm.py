@@ -24,11 +24,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:                         # pragma: no cover
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _kernel(x_ref, s_ref, o_ref, scale_ref, *, eps: float):
@@ -46,8 +42,6 @@ def _norm_rows(xf: jax.Array, scale: jax.Array, eps: float, br: int,
                interpret: bool) -> jax.Array:
     """xf: (rows, d) with rows % br == 0 — no padded tiles."""
     rows, d = xf.shape
-    scratch = ([_VMEM((d,), jnp.float32)] if _VMEM is not None
-               else [pl.MemorySpace.ANY])  # pragma: no cover (non-TPU)
     return pl.pallas_call(
         functools.partial(_kernel, eps=eps),
         grid=(rows // br,),
@@ -57,7 +51,7 @@ def _norm_rows(xf: jax.Array, scale: jax.Array, eps: float, br: int,
         ],
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(xf.shape, xf.dtype),
-        scratch_shapes=scratch,
+        scratch_shapes=[pltpu.VMEM((d,), jnp.float32)],
         interpret=interpret,
     )(xf, scale)
 

@@ -23,15 +23,12 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:                         # pragma: no cover
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 
-def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, st_out_ref, state_ref,
+def _kernel(a_ref, x_ref, dt_ref, b_ref, c_ref, y_ref, st_out_ref, state_ref,
             *, chunk: int, n_chunks: int):
+    hi = pl.program_id(1)
     ci = pl.program_id(2)
 
     @pl.when(ci == 0)
@@ -39,41 +36,49 @@ def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, st_out_ref, state_ref,
         state_ref[...] = jnp.zeros_like(state_ref)
 
     x = x_ref[0, 0, 0].astype(jnp.float32)        # (Q, P)
-    dt = dt_ref[0, 0, 0].astype(jnp.float32)      # (Q,)
-    a = a_ref[0]                                  # scalar decay rate (<0)
+    dt = dt_ref[0, 0, 0].astype(jnp.float32)      # (Q, 1) column
+    a = a_ref[hi]                                 # scalar decay rate (<0)
     b = b_ref[0, 0].astype(jnp.float32)           # (Q, N)
     c = c_ref[0, 0].astype(jnp.float32)           # (Q, N)
 
-    da = dt * a                                   # (Q,)
-    cum = jnp.cumsum(da)                          # (Q,)
-    # within-chunk: L[i,j] = exp(cum_i - cum_j) for i >= j
-    li = cum[:, None] - cum[None, :]
+    # cumulative decay as a triangular matmul (Mosaic has no cumsum), kept
+    # 2-D: cum as a column (Q, 1) and as a row (1, Q)
+    da = dt * a                                   # (Q, 1)
     iota_i = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     iota_j = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    ldec = jnp.where(iota_i >= iota_j, jnp.exp(li), 0.0)
+    causal = iota_i >= iota_j
+    tril = causal.astype(jnp.float32)
+    cum = jax.lax.dot_general(
+        tril, da, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)        # (Q, 1)
+    cum_row = jax.lax.dot_general(
+        da, tril, (((0,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)        # (1, Q)
+    total = jnp.sum(da, axis=0, keepdims=True)     # (1, 1) = cum_last
+    # within-chunk: L[i,j] = exp(cum_i - cum_j) for i >= j
+    ldec = jnp.where(causal, jnp.exp(cum - cum_row), 0.0)
     scores = jax.lax.dot_general(
         c, b, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)        # (Q, Q)
-    w = scores * ldec * dt[None, :]                # weight on x_j
+    # weight on x_j is scores_ij * L_ij * dt_j: fold dt_j into x's rows
     y_diag = jax.lax.dot_general(
-        w, x, (((1,), (0,)), ((), ())),
+        scores * ldec, x * dt, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)        # (Q, P)
 
     # cross-chunk: y_off = (C decayed) @ state^T  (state: (P, N))
     st = state_ref[...]
-    c_dec = c * jnp.exp(cum)[:, None]
+    c_dec = c * jnp.exp(cum)
     y_off = jax.lax.dot_general(
         c_dec, st, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)        # (Q, P)
     y_ref[0, 0, 0] = (y_diag + y_off).astype(y_ref.dtype)
 
     # state update: state = exp(cum_last) * state + sum_j w_j x_j b_j^T
-    dec_end = jnp.exp(cum[-1] - cum) * dt          # (Q,)
-    xw = x * dec_end[:, None]                      # (Q, P)
+    xw = x * (jnp.exp(total - cum) * dt)           # (Q, P)
     upd = jax.lax.dot_general(
         xw, b, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)        # (P, N)
-    state_ref[...] = jnp.exp(cum[-1]) * st + upd
+    state_ref[...] = jnp.exp(total) * st + upd
 
     @pl.when(ci == n_chunks - 1)
     def _emit_state():
@@ -101,23 +106,24 @@ def ssd_scan(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
         c = jnp.pad(c, ((0, 0), (0, pad), (0, 0)))
     s = s_orig + pad
     nc = s // chunk
-    # layout: (B, H, nc, Q, ...) so (b, h) are grid-major, chunks minor
+    # layout: (B, H, nc, Q, ...) so (b, h) are grid-major, chunks minor;
+    # dt travels as a (Q, 1) column and ``a`` as an SMEM scalar table, so
+    # every block's trailing dims meet the TPU tiling rule
     xr = x.transpose(0, 2, 1, 3).reshape(bs, h, nc, chunk, p)
-    dtr = dt.transpose(0, 2, 1).reshape(bs, h, nc, chunk)
+    dtr = dt.transpose(0, 2, 1).reshape(bs, h, nc, chunk, 1)
     br = b.reshape(bs, nc, chunk, n)
     cr = c.reshape(bs, nc, chunk, n)
 
     kern = functools.partial(_kernel, chunk=chunk, n_chunks=nc)
-    scratch = [_VMEM((p, n), jnp.float32)] if _VMEM is not None else []
     y, st = pl.pallas_call(
         kern,
         grid=(bs, h, nc),
         in_specs=[
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, 1, 1, chunk, p),
                          lambda bi, hi, ci: (bi, hi, ci, 0, 0)),
-            pl.BlockSpec((1, 1, 1, chunk),
-                         lambda bi, hi, ci: (bi, hi, ci, 0)),
-            pl.BlockSpec((1,), lambda bi, hi, ci: (hi,)),
+            pl.BlockSpec((1, 1, 1, chunk, 1),
+                         lambda bi, hi, ci: (bi, hi, ci, 0, 0)),
             pl.BlockSpec((1, 1, chunk, n), lambda bi, hi, ci: (bi, ci, 0, 0)),
             pl.BlockSpec((1, 1, chunk, n), lambda bi, hi, ci: (bi, ci, 0, 0)),
         ],
@@ -130,8 +136,8 @@ def ssd_scan(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
             jax.ShapeDtypeStruct((bs, h, nc, chunk, p), x.dtype),
             jax.ShapeDtypeStruct((bs, h, p, n), jnp.float32),
         ],
-        scratch_shapes=scratch,
+        scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
         interpret=interpret,
-    )(xr, dtr, a.astype(jnp.float32), br, cr)
+    )(a.astype(jnp.float32), xr, dtr, br, cr)
     y = y.reshape(bs, h, s, p).transpose(0, 2, 1, 3)
     return (y[:, :s_orig] if pad else y), st

@@ -1,7 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^ MUST precede any jax import: device count locks at first backend init.
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 For each cell this proves, without hardware:
@@ -22,6 +18,7 @@ import argparse
 import dataclasses
 import functools
 import json
+import os
 import time
 import traceback
 from typing import Dict, Optional
@@ -207,6 +204,8 @@ def _save(rec: Dict, out_dir: str) -> Dict:
 
 
 def main() -> None:
+    # 512 host devices; takes effect only before JAX's first backend use
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="all")
     ap.add_argument("--shape", default="all")

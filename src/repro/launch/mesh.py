@@ -2,8 +2,8 @@
 
 ``make_production_mesh`` is a FUNCTION (not a module-level constant) so that
 importing this module never touches JAX device state — the dry-run sets
-``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before any JAX
-import and only then builds meshes.
+``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before JAX's
+first backend use and only then builds meshes.
 
 Single pod:  (16, 16)    -> ("data", "model")     = 256 chips (v5e pod)
 Multi-pod:   (2, 16, 16) -> ("pod", "data", "model") = 512 chips, the 'pod'

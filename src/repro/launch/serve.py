@@ -19,6 +19,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as model_lib
 from repro.serve.serve_step import BatchedServer, Request
 
@@ -34,6 +35,7 @@ def make_requests(cfg, n: int, prompt_len: int, max_new: int,
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")

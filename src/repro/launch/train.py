@@ -18,8 +18,8 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import json
-import os
 import time
+from typing import Callable
 
 import jax
 
@@ -27,6 +27,8 @@ from repro.configs import get_config
 from repro.core.cluster import heterogeneous_zone
 from repro.core.planner.objectives import MAX_THROUGHPUT, Objective
 from repro.core.planner.search import plan_for
+from repro.launch.compile_cache import enable_compile_cache
+from repro.models.config import ModelConfig
 from repro.train import data as data_lib
 from repro.train import optimizer as opt_lib
 from repro.train.elastic import ElasticTrainer, RuntimePlan
@@ -43,7 +45,25 @@ def parse_cluster(spec: str):
     return heterogeneous_zone(cap)
 
 
+def build_trainer(cfg: ModelConfig, *, seq_len: int, global_batch: int,
+                  num_micro: int, lr: float, steps: int, workdir: str,
+                  checkpoint_every: int,
+                  plan_fn: Callable[[int], RuntimePlan]) -> ElasticTrainer:
+    """The trainer ``main`` runs, before ``build``: synthetic data,
+    AdamW with a 10-step warmup, and ``plan_fn`` mapping a device count
+    to the (dp, tp) layout."""
+    data_cfg = data_lib.DataConfig(
+        seq_len=seq_len, global_batch=global_batch,
+        num_microbatches=num_micro)
+    opt_cfg = opt_lib.OptimizerConfig(lr=lr, warmup_steps=10,
+                                      total_steps=steps)
+    return ElasticTrainer(cfg, opt_cfg, data_cfg, workdir=workdir,
+                          checkpoint_every=checkpoint_every,
+                          plan_fn=plan_fn)
+
+
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true",
@@ -79,14 +99,10 @@ def main() -> None:
 
     n_dev = len(jax.devices())
     dp = args.dp or max(1, n_dev // args.tp)
-    data_cfg = data_lib.DataConfig(
-        seq_len=args.seq_len, global_batch=args.global_batch,
-        num_microbatches=args.num_micro)
-    opt_cfg = opt_lib.OptimizerConfig(lr=args.lr, warmup_steps=10,
-                                      total_steps=args.steps)
-    trainer = ElasticTrainer(
-        cfg, opt_cfg, data_cfg, workdir=args.workdir,
-        checkpoint_every=args.checkpoint_every,
+    trainer = build_trainer(
+        cfg, seq_len=args.seq_len, global_batch=args.global_batch,
+        num_micro=args.num_micro, lr=args.lr, steps=args.steps,
+        workdir=args.workdir, checkpoint_every=args.checkpoint_every,
         plan_fn=lambda n: RuntimePlan(
             n_devices=dp * args.tp, dp=dp, tp=args.tp,
             num_microbatches=args.num_micro))

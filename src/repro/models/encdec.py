@@ -111,7 +111,7 @@ def encode(cfg: ModelConfig, params, frames: jax.Array,
 
 def forward(cfg: ModelConfig, params, batch: Dict[str, jax.Array], *,
             mesh: Optional[Mesh] = None, return_cache: bool = False,
-            attn_impl: Optional[str] = None):
+            attn_impl: Optional[str] = None, differentiated: bool = False):
     enc = encode(cfg, params, batch["frames"], mesh)
     tokens = batch["tokens"]
     bs, s = tokens.shape

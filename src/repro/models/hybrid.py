@@ -81,14 +81,14 @@ def _group_params(params, g: int, size: int):
 
 def forward(cfg: ModelConfig, params, batch: Dict[str, jax.Array], *,
             mesh: Optional[Mesh] = None, return_cache: bool = False,
-            attn_impl: Optional[str] = None):
+            attn_impl: Optional[str] = None, differentiated: bool = False):
     tokens = batch["tokens"]
     bs, s = tokens.shape
     x = params["embed"][tokens].astype(cfg.dtype)
     if mesh is not None:
         x = constrain(x, batch_spec(mesh, bs, None, None))
     positions = jnp.arange(s)
-    impl = attn_impl or L.pick_attn_impl(cfg.attn_impl, s)
+    impl = L.pick_attn_impl(attn_impl or cfg.attn_impl, s, differentiated)
     ng, ae = n_groups(cfg), cfg.attn_every
 
     attn_caches = []

@@ -8,7 +8,7 @@ Attention comes in three selectable implementations:
   window   sliding-window attention that is *linear* in sequence length: a
            scan over query blocks each attending to a dynamic KV slice of
            window+block tokens (mixtral SWA / long-context prefill).
-  pallas   the TPU kernel in repro.kernels (validated in interpret mode).
+  pallas   the TPU kernel in repro.kernels, forward only (no backward pass).
 
 All softmax statistics are computed in float32 regardless of input dtype.
 """
@@ -261,13 +261,24 @@ def attention(q, k, v, *, impl: str = "chunked", causal: bool = True,
                         block_remat=block_remat)
 
 
-def pick_attn_impl(cfg_impl: str, seq_len: int,
+def pick_attn_impl(cfg_impl: str, seq_len: int, differentiated: bool,
                    backend: Optional[str] = None) -> str:
-    """Resolve ``attn_impl="auto"``: the Pallas kernel wherever it
-    compiles to Mosaic (TPU), else naive for short sequences and the
-    chunked online-softmax beyond (full scores don't fit)."""
+    """Resolve the attention implementation for one call site.
+
+    The Pallas kernels have no backward pass, so a differentiated caller
+    (the training loss, a pipeline stage under ``jax.vjp``) always runs
+    XLA attention, and an explicit ``"pallas"`` there is an error.
+    Forward-only callers (prefill, serving) resolve ``"auto"`` to the
+    kernel wherever it compiles to Mosaic (TPU).  Otherwise ``"auto"`` is
+    naive for short sequences and the chunked online-softmax beyond (full
+    scores don't fit)."""
+    if differentiated and cfg_impl == "pallas":
+        raise ValueError(
+            "attn_impl='pallas' on a differentiated path: the Pallas "
+            "attention kernel has no backward pass; train with 'auto', "
+            "'naive' or 'chunked'")
     if cfg_impl != "auto":
         return cfg_impl
-    if (backend or jax.default_backend()) == "tpu":
+    if not differentiated and (backend or jax.default_backend()) == "tpu":
         return "pallas"
     return "naive" if seq_len <= 2048 else "chunked"

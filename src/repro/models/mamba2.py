@@ -237,7 +237,7 @@ def state_decls(cfg: ModelConfig, batch: int, max_len: int = 0) -> Dict[str, Dec
 
 def forward(cfg: ModelConfig, params, batch: Dict[str, jax.Array], *,
             mesh: Optional[Mesh] = None, return_cache: bool = False,
-            attn_impl: Optional[str] = None):
+            attn_impl: Optional[str] = None, differentiated: bool = False):
     tokens = batch["tokens"]
     bs = tokens.shape[0]
     x = params["embed"][tokens].astype(cfg.dtype)

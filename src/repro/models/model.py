@@ -2,7 +2,8 @@
 
 Every family module exposes the same surface:
     decls(cfg) -> pytree of Decl
-    forward(cfg, params, batch, *, mesh, return_cache, attn_impl)
+    forward(cfg, params, batch, *, mesh, return_cache, attn_impl,
+            differentiated)
     decode(cfg, params, cache, tokens, *, mesh)
     cache_decls(cfg, batch, max_len)   (or state_decls for ssm)
 This module is the single entry point used by the trainer, server,
@@ -77,13 +78,17 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
 
 def forward(cfg: ModelConfig, params, batch, *, mesh: Optional[Mesh] = None,
             return_cache: bool = False, attn_impl: Optional[str] = None,
-            return_hidden: bool = False):
+            return_hidden: bool = False, differentiated: bool = False):
+    """``differentiated=True`` for callers that take gradients through
+    the forward (``loss_fn``): it keeps the Pallas kernels, which have no
+    backward pass, out of the graph."""
     kw = {}
     if return_hidden:        # transformer families only (chunked loss)
         kw["return_hidden"] = True
     return get_module(cfg).forward(cfg, params, batch, mesh=mesh,
                                    return_cache=return_cache,
-                                   attn_impl=attn_impl, **kw)
+                                   attn_impl=attn_impl,
+                                   differentiated=differentiated, **kw)
 
 
 def decode(cfg: ModelConfig, params, cache, tokens, *,
@@ -102,7 +107,7 @@ def loss_fn(cfg: ModelConfig, params, batch, *,
     """
     if cfg.logits_chunk and cfg.family in ("dense", "moe", "vlm"):
         return _chunked_loss(cfg, params, batch, mesh=mesh)
-    logits = forward(cfg, params, batch, mesh=mesh)
+    logits = forward(cfg, params, batch, mesh=mesh, differentiated=True)
     nll_sum, n_tok, n_corr = masked_ce_sums(logits, batch["labels"])
     denom = jnp.maximum(n_tok, 1)
     loss = nll_sum / denom
@@ -112,7 +117,8 @@ def loss_fn(cfg: ModelConfig, params, batch, *,
 
 def _chunked_loss(cfg: ModelConfig, params, batch, *,
                   mesh: Optional[Mesh] = None):
-    x, head = forward(cfg, params, batch, mesh=mesh, return_hidden=True)
+    x, head = forward(cfg, params, batch, mesh=mesh, return_hidden=True,
+                      differentiated=True)
     labels = batch["labels"]
     b, s, d = x.shape
     c = min(cfg.logits_chunk, s)

@@ -12,7 +12,9 @@ Design notes
   ``dist/sharding.py``); GQA heads that don't divide the 16-way model axis
   fall back to replication automatically.
 * The same ``forward`` serves train (full seq, causal) and prefill (returns
-  the KV cache); ``decode`` runs one token against the cache.
+  the KV cache); ``decode`` runs one token against the cache.  Training
+  passes ``differentiated=True``, which keeps the forward-only Pallas
+  kernels out of the graph (``layers.pick_attn_impl``).
 """
 from __future__ import annotations
 
@@ -161,7 +163,8 @@ def _remat(fn, mode: str):
 
 def forward(cfg: ModelConfig, params, batch: Dict[str, jax.Array], *,
             mesh: Optional[Mesh] = None, return_cache: bool = False,
-            attn_impl: Optional[str] = None, return_hidden: bool = False):
+            attn_impl: Optional[str] = None, return_hidden: bool = False,
+            differentiated: bool = False):
     """Returns logits (B,S,V) and optionally the KV cache (ring for SWA)."""
     tokens = batch["tokens"]
     b = tokens.shape[0]
@@ -171,7 +174,7 @@ def forward(cfg: ModelConfig, params, batch: Dict[str, jax.Array], *,
         x = jnp.concatenate([patches.astype(cfg.dtype), x], axis=1)
     s = x.shape[1]
     positions = jnp.arange(s)
-    impl = attn_impl or L.pick_attn_impl(cfg.attn_impl, s)
+    impl = L.pick_attn_impl(attn_impl or cfg.attn_impl, s, differentiated)
     if mesh is not None:
         x = constrain(x, batch_spec(mesh, b, None, None))
 

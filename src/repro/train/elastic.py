@@ -28,6 +28,7 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro.dist import mesh as mesh_lib
 from repro.dist import sharding as shd
 from repro.models import model as model_lib
 from repro.models.config import ModelConfig
@@ -129,10 +130,9 @@ class ElasticTrainer:
 
     def build(self, n_devices: int, init_key: Optional[jax.Array] = None):
         """Initial build or kill-free rebuild onto ``n_devices`` devices."""
-        devices = jax.devices()[:n_devices]
         plan = self.plan_fn(n_devices)
-        mesh = Mesh(
-            np.asarray(devices).reshape(plan.mesh_shape()), ("data", "model"))
+        mesh = mesh_lib.data_model_mesh(*plan.mesh_shape(),
+                                        jax.devices()[:n_devices])
         pshard, oshard = self._shardings(mesh)
         live = self.params is not None
         with jax.set_mesh(mesh):

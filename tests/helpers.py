@@ -17,11 +17,7 @@ def run_py(code: str, devices: int = 8, timeout: int = 600):
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = os.path.join(REPO, "src")
-    # jax API shims (set_mesh / AxisType on 0.4.x) before the test body's
-    # own jax imports — same surface the repro modules install.
-    preamble = "import repro.dist.compat\n"
-    p = subprocess.run([sys.executable, "-c",
-                        preamble + textwrap.dedent(code)],
+    p = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
                        capture_output=True, text=True, timeout=timeout,
                        env=env, cwd=REPO)
     if p.returncode != 0:

@@ -68,6 +68,38 @@ def test_mpmd_pipeline_heterogeneous_tp_matches_single_program():
     assert "OK" in out
 
 
+def test_mpmd_stage_programs_bypass_persistent_cache(tmp_path):
+    """No stage program is written to (or read from) the persistent
+    compile cache, while other programs in the process still are."""
+    out = run_py(f"""
+        import dataclasses, os, jax, numpy as np
+        from repro.configs import get_config
+        from repro.dist.pipeline import MPMDPipeline, even_stages
+        from repro.train import optimizer as opt_lib
+        jax.config.update("jax_compilation_cache_dir", {str(tmp_path)!r})
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+        cfg = dataclasses.replace(get_config("smollm_360m").reduced(),
+                                  n_layers=2, tie_embeddings=False)
+        pipe = MPMDPipeline(cfg, even_stages(cfg, tps=[1, 1]),
+                            opt_lib.OptimizerConfig(lr=1e-3))
+        pipe.init_params(jax.random.PRNGKey(0))
+        toks = np.random.default_rng(0).integers(
+            0, cfg.vocab_size, (2, 2, 9)).astype(np.int32)
+        pipe.train_step({{"tokens": toks[..., :-1], "labels": toks[..., 1:]}})
+        def control(x):
+            return x * 2
+        jax.jit(control)(np.ones(4, np.float32)).block_until_ready()
+        print("FILES", sorted(f.split("-")[0] for f in os.listdir(
+            {str(tmp_path)!r})))
+    """, devices=2, timeout=600)
+    files = out.split("FILES", 1)[1]
+    assert "jit_control" in files, files
+    for name in ("jit_fwd", "jit_bwd_first", "jit_bwd_last", "jit_update",
+                 "jit_init_state", "jit__lambda"):
+        assert f"'{name}'" not in files, files
+
+
 def test_sharded_train_step_matches_single_device():
     out = run_py("""
         import jax, numpy as np, jax.numpy as jnp

@@ -257,6 +257,26 @@ def test_autotune_tuned_blocks_match_defaults(tmp_path):
                                rtol=2e-5, atol=2e-5)
 
 
+def test_device_kind_maps_to_catalog(monkeypatch):
+    """The autotuner files tables under the catalog key the profiler asks
+    for; an unknown device kind is an error, never a default peak."""
+    from repro.core.profiler import hw_specs
+    from repro.kernels import autotune as at
+    assert hw_specs.accelerator_for_kind("TPU v5 lite") == "tpu-v5e"
+    assert set(hw_specs.DEVICE_KINDS.values()) <= set(hw_specs.ACCELERATORS)
+    with pytest.raises(KeyError, match="unknown device_kind"):
+        hw_specs.accelerator_for_kind("TPU v99")
+
+    class FakeDevice:
+        platform, device_kind = "tpu", "TPU v5 lite"
+
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: [FakeDevice()])
+    assert at.default_chip() == "tpu-v5e"
+    FakeDevice.device_kind = "TPU v99"
+    with pytest.raises(KeyError, match="unknown device_kind"):
+        at.default_chip()
+
+
 # --- models/layers.py pallas dispatch path -----------------------------------
 
 @pytest.mark.parametrize("s", [128, 100])
@@ -290,10 +310,33 @@ def test_attention_pallas_window_falls_back():
 
 def test_pick_attn_impl():
     from repro.models import layers as L
-    assert L.pick_attn_impl("chunked", 128) == "chunked"
-    assert L.pick_attn_impl("auto", 128, backend="tpu") == "pallas"
-    assert L.pick_attn_impl("auto", 128, backend="cpu") == "naive"
-    assert L.pick_attn_impl("auto", 8192, backend="cpu") == "chunked"
+    assert L.pick_attn_impl("chunked", 128, False) == "chunked"
+    assert L.pick_attn_impl("auto", 128, False, backend="tpu") == "pallas"
+    assert L.pick_attn_impl("auto", 128, False, backend="cpu") == "naive"
+    assert L.pick_attn_impl("auto", 8192, False, backend="cpu") == "chunked"
+    # differentiated callers never get the forward-only kernel
+    assert L.pick_attn_impl("auto", 128, True, backend="tpu") == "naive"
+    assert L.pick_attn_impl("auto", 8192, True, backend="tpu") == "chunked"
+    with pytest.raises(ValueError, match="no backward pass"):
+        L.pick_attn_impl("pallas", 128, True)
+
+
+def test_loss_fn_rejects_pallas_attention():
+    """An explicit Pallas attention on the training path is a readable
+    error at trace time, not the Pallas JVP assertion."""
+    import dataclasses
+    from repro.configs import get_config
+    from repro.models import model as model_lib
+    cfg = dataclasses.replace(get_config("opt_350m").reduced(),
+                              attn_impl="pallas")
+    params = model_lib.init(cfg, jax.random.PRNGKey(0))
+    toks = jnp.asarray(RNG.integers(0, cfg.vocab_size, (2, 17)), jnp.int32)
+    batch = {"tokens": toks[:, :-1], "labels": toks[:, 1:]}
+    with pytest.raises(ValueError, match="no backward pass"):
+        jax.grad(lambda p: model_lib.loss_fn(cfg, p, batch)[0])(params)
+    # the forward-only path (prefill) still runs the kernel
+    logits = model_lib.forward(cfg, params, batch)
+    assert bool(jnp.all(jnp.isfinite(logits)))
 
 
 def test_attn_decode_pallas_impl():
