@@ -29,9 +29,10 @@ from repro.manager.events import EventBus
 from repro.manager.monitor import AvailabilityMonitor
 from repro.manager.replan import IncrementalReplanner
 from repro.core.planner.objectives import MAX_THROUGHPUT, Objective
-from repro.telemetry import (EXPECTED_VERDICT, ChaosHarness, DetectorBank,
-                             FaultInjector, FaultSpec, SimulatedWorld,
-                             TelemetryBus)
+from repro.telemetry.bus import TelemetryBus
+from repro.telemetry.detectors import DetectorBank
+from repro.telemetry.faults import (EXPECTED_VERDICT, ChaosHarness,
+                                    FaultInjector, FaultSpec, SimulatedWorld)
 
 from benchmarks.common import emit
 

@@ -22,6 +22,7 @@ from jax.sharding import Mesh
 from repro.dist import sharding as shd
 from repro.models import encdec, hybrid, mamba2, transformer
 from repro.models.config import ModelConfig
+from repro.telemetry import trace
 
 IGNORE_LABEL = -100
 
@@ -108,9 +109,10 @@ def loss_fn(cfg: ModelConfig, params, batch, *,
     if cfg.logits_chunk and cfg.family in ("dense", "moe", "vlm"):
         return _chunked_loss(cfg, params, batch, mesh=mesh)
     logits = forward(cfg, params, batch, mesh=mesh, differentiated=True)
-    nll_sum, n_tok, n_corr = masked_ce_sums(logits, batch["labels"])
-    denom = jnp.maximum(n_tok, 1)
-    loss = nll_sum / denom
+    with jax.named_scope(trace.LOSS):
+        nll_sum, n_tok, n_corr = masked_ce_sums(logits, batch["labels"])
+        denom = jnp.maximum(n_tok, 1)
+        loss = nll_sum / denom
     metrics = {"loss": loss, "tokens": n_tok, "accuracy": n_corr / denom}
     return loss, metrics
 
@@ -135,14 +137,17 @@ def _chunked_loss(cfg: ModelConfig, params, batch, *,
     def body(carry, xs):
         nll_sum, n_tok, n_correct = carry
         xi, li = xs
-        logits = (xi @ head.astype(xi.dtype)).astype(jnp.float32)
-        s_nll, s_tok, s_corr = masked_ce_sums(logits, li)
+        with jax.named_scope(trace.HEAD):
+            logits = (xi @ head.astype(xi.dtype)).astype(jnp.float32)
+        with jax.named_scope(trace.LOSS):
+            s_nll, s_tok, s_corr = masked_ce_sums(logits, li)
         return (nll_sum + s_nll, n_tok + s_tok, n_correct + s_corr), None
 
     (nll_sum, n_tok, n_corr), _ = jax.lax.scan(
         body, (jnp.float32(0.0), jnp.int32(0), jnp.int32(0)), (xc, lc))
-    denom = jnp.maximum(n_tok, 1)
-    loss = nll_sum / denom
+    with jax.named_scope(trace.LOSS):
+        denom = jnp.maximum(n_tok, 1)
+        loss = nll_sum / denom
     return loss, {"loss": loss, "tokens": n_tok,
                   "accuracy": n_corr / denom}
 

@@ -29,6 +29,7 @@ from repro.dist.sharding import Decl, batch_spec, constrain
 from repro.models import layers as L
 from repro.models import moe as moe_mod
 from repro.models.config import ModelConfig
+from repro.telemetry import trace
 
 
 # --- declarations ---------------------------------------------------------------
@@ -109,9 +110,10 @@ def attn_delta(cfg: ModelConfig, p, x, positions, impl: str,
     q, k, v = _qkv(cfg, p, h, positions)
     if mesh is not None:
         q = constrain(q, batch_spec(mesh, q.shape[0], None, "model", None))
-    o = L.attention(q, k, v, impl=impl, causal=True, window=cfg.window,
-                    q_pos=positions, k_pos=positions,
-                    block_remat=cfg.attn_block_remat)
+    with jax.named_scope(trace.ATTENTION):
+        o = L.attention(q, k, v, impl=impl, causal=True, window=cfg.window,
+                        q_pos=positions, k_pos=positions,
+                        block_remat=cfg.attn_block_remat)
     return jnp.einsum("bshk,hkd->bsd", o, p["wo"]), (k, v)
 
 
@@ -123,13 +125,14 @@ def attn_block(cfg: ModelConfig, p, x, positions, impl: str,
 
 def _ffn(cfg: ModelConfig, p, h, mesh: Optional[Mesh]):
     """FFN applied to an already-normed hidden state."""
-    if cfg.family == "moe":
-        return moe_mod.moe_ffn(cfg, p, h, mesh)
-    if cfg.ffn_act == "swiglu":
-        return L.swiglu(h, p["w_gate"], p["w_up"], p["w_down"])
-    act = (jax.nn.gelu if cfg.ffn_act == "gelu"
-           else lambda u: jnp.square(jax.nn.relu(u)))
-    return act(h @ p["w_up"]) @ p["w_down"]
+    with jax.named_scope(trace.MLP):
+        if cfg.family == "moe":
+            return moe_mod.moe_ffn(cfg, p, h, mesh)
+        if cfg.ffn_act == "swiglu":
+            return L.swiglu(h, p["w_gate"], p["w_up"], p["w_down"])
+        act = (jax.nn.gelu if cfg.ffn_act == "gelu"
+               else lambda u: jnp.square(jax.nn.relu(u)))
+        return act(h @ p["w_up"]) @ p["w_down"]
 
 
 def ffn_block(cfg: ModelConfig, p, x, mesh: Optional[Mesh]):
@@ -193,7 +196,8 @@ def forward(cfg: ModelConfig, params, batch: Dict[str, jax.Array], *,
     head = (params["embed"].T if cfg.tie_embeddings else params["lm_head"])
     if return_hidden:
         return x, head
-    logits = (x @ head.astype(cfg.dtype)).astype(jnp.float32)
+    with jax.named_scope(trace.HEAD):
+        logits = (x @ head.astype(cfg.dtype)).astype(jnp.float32)
     if mesh is not None:
         logits = constrain(logits, batch_spec(mesh, b, None, "model"))
     if return_cache:

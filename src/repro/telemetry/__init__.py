@@ -1,30 +1,15 @@
-"""Telemetry, online detection, root-cause analysis and fault injection.
+"""Telemetry, online detection, root-cause analysis, fault injection and
+the program's own tracing.
 
 The control plane's sensing layer (ROADMAP: "telemetry, fault injection
 and self-healing ops"): runtime and simulator producers emit one shared
-sample schema onto :class:`TelemetryBus`; :class:`DetectorBank` turns the
-noisy streams into typed manager events; :class:`RootCauseAnalyzer`
-classifies each event into a remediation; :class:`ChaosHarness` closes
-the loop against injected ground-truth faults.
-"""
-from repro.telemetry.bus import (METRICS, JsonlWriter, Sample, TelemetryBus,
-                                 read_jsonl, wall_clock)
-from repro.telemetry.detectors import (Anomaly, DetectorBank, DetectorConfig,
-                                       HeartbeatDetector, StreamDetector)
-from repro.telemetry.faults import (EXPECTED_VERDICT, FAULT_KINDS,
-                                    ChaosHarness, ChaosReport, FaultInjector,
-                                    FaultSpec, SimulatedWorld, degrade_link)
-from repro.telemetry.rca import (DATA_STALL, NODE_FAILURE, REMEDIATION,
-                                 SLOW_CHIP, SLOW_LINK, UNKNOWN, RootCause,
-                                 RootCauseAnalyzer)
+sample schema onto ``bus.TelemetryBus``; ``detectors.DetectorBank`` turns
+the noisy streams into typed manager events; ``rca.RootCauseAnalyzer``
+classifies each event into a remediation; ``faults.ChaosHarness`` closes
+the loop against injected ground-truth faults.  ``trace`` names the
+program's work in a profiler trace.
 
-__all__ = [
-    "METRICS", "JsonlWriter", "Sample", "TelemetryBus", "read_jsonl",
-    "wall_clock",
-    "Anomaly", "DetectorBank", "DetectorConfig", "HeartbeatDetector",
-    "StreamDetector",
-    "EXPECTED_VERDICT", "FAULT_KINDS", "ChaosHarness", "ChaosReport",
-    "FaultInjector", "FaultSpec", "SimulatedWorld", "degrade_link",
-    "DATA_STALL", "NODE_FAILURE", "REMEDIATION", "SLOW_CHIP", "SLOW_LINK",
-    "UNKNOWN", "RootCause", "RootCauseAnalyzer",
-]
+Import from the submodule that defines a name: the package imports
+nothing, so the models can use ``trace`` without loading the control
+plane.
+"""

@@ -19,7 +19,6 @@ Metrics (the schema):
   p2p_time      (stage_a, stage_b, ra, rb) per-microbatch transfer seconds
   sync_time     (stage,)                   DP all-reduce seconds
   data_stall    ()                         input-pipeline wait seconds
-  hbm_headroom  (stage, replica)           usable HBM minus peak, bytes
   heartbeat     (stage, replica)           1.0 (presence; absence = hang)
   ============= ========================== ==============================
 
@@ -39,7 +38,7 @@ from typing import (Callable, Deque, Dict, Iterable, List, Mapping,
                     Optional, Tuple)
 
 METRICS = ("step_time", "fwd_time", "bwd_time", "p2p_time", "sync_time",
-           "data_stall", "hbm_headroom", "heartbeat")
+           "data_stall", "heartbeat")
 
 
 @dataclasses.dataclass(frozen=True)

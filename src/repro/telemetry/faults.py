@@ -43,7 +43,6 @@ from repro.core.planner.objectives import MAX_THROUGHPUT, Objective
 from repro.core.planner.plan import ParallelPlan
 from repro.core.profiler.analytic import DTYPE_BYTES, JobProfile, TrainJob
 from repro.core.simulator import engine as eng
-from repro.core.simulator import memory as mem_mod
 from repro.core.simulator import timing
 from repro.manager.events import EventBus, NodeFailure
 from repro.manager.monitor import AvailabilityMonitor
@@ -174,9 +173,9 @@ class SimulatedWorld:
     ``record_timeline=True`` and converts the tagged task timeline into
     the shared :class:`~repro.telemetry.bus.Sample` schema — fwd/bwd per
     worker, p2p per boundary channel, sync per stage, plus heartbeats
-    (suppressed for hung pools), HBM headroom, step time and data-stall
-    seconds.  The cluster passed here is the *physical* world; remediated
-    planner views never change the physics, only the plan.
+    (suppressed for hung pools), step time and data-stall seconds.  The
+    cluster passed here is the *physical* world; remediated planner views
+    never change the physics, only the plan.
     """
 
     def __init__(self, profile: JobProfile, plan: ParallelPlan,
@@ -208,12 +207,6 @@ class SimulatedWorld:
             self.chain_of = None
             self._m_extra = total - total_eff
         self.base_spec = spec
-        mem = mem_mod.plan_memory(self.profile, plan, mem_mod.DEFAULT_MEM)
-        self._headroom = {
-            (s, r): (row["usable"] - row["peak"])
-            for s in range(spec.n_stages)
-            for r in range(spec.n_replicas[s])
-            for row in [mem[s][self._rep_idx(s, r)]]}
         # chips the plan places in each (zone, type) pool — the heartbeat
         # meta a NodeFailure needs to shrink the availability snapshot
         self._pool_chips: Dict[Tuple[str, str], int] = {}
@@ -292,9 +285,6 @@ class SimulatedWorld:
                 emit(Sample("heartbeat", (s, r), t, step, 1.0,
                             {"zone": rep.zone, "acc_type": rep.gpu_type,
                              "chips": self._pool_chips[pool]}))
-            emit(Sample("hbm_headroom", (s, r), t, step,
-                        self._headroom[(s, r)],
-                        {"zone": rep.zone, "acc_type": rep.gpu_type}))
         emit(Sample("data_stall", (), t, step, stall))
         emit(Sample("step_time", (), t, step, t_step))
 

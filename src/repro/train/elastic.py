@@ -21,7 +21,6 @@ surfaced in metrics so tests/examples can assert on it.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -32,6 +31,7 @@ from repro.dist import mesh as mesh_lib
 from repro.dist import sharding as shd
 from repro.models import model as model_lib
 from repro.models.config import ModelConfig
+from repro.telemetry import trace
 from repro.train import checkpoint as ckpt_lib
 from repro.train import data as data_lib
 from repro.train import optimizer as opt_lib
@@ -176,21 +176,21 @@ class ElasticTrainer:
 
     # --- events ----------------------------------------------------------------------
     def on_availability_change(self, n_devices: int, failure: bool = False):
-        t0 = time.perf_counter()
         step_at_event = self.step
-        if failure:
-            self.restore_from_checkpoint(n_devices)
-            kind = "rollback"
-        else:
-            self.build(n_devices)
-            kind = "kill-free"
+        with trace.Span(trace.RECONFIG) as reconfig:
+            if failure:
+                self.restore_from_checkpoint(n_devices)
+                kind = "rollback"
+            else:
+                self.build(n_devices)
+                kind = "kill-free"
         # step times change scale with the device set; a stale median would
         # flag every post-reconfig (re-jit) step as a straggler.
         self.detector.times.clear()
         self.reconfigs.append({
             "step": step_at_event, "resumed_at": self.step,
             "n_devices": n_devices, "kind": kind,
-            "reconfig_s": time.perf_counter() - t0})
+            "reconfig_s": reconfig.seconds})
 
     # --- telemetry -------------------------------------------------------------------
     def _emit_telemetry(self, step_s: float, data_s: float) -> None:
@@ -225,28 +225,44 @@ class ElasticTrainer:
             if self.step in ev:
                 for n, failure in ev.pop(self.step):
                     self.on_availability_change(n, failure)
-            t_data = time.perf_counter()
-            batch = self.data.batch(self.step)
-            t_data = time.perf_counter() - t_data      # input-pipeline wait
-            with jax.set_mesh(self.mesh):
-                t0 = time.perf_counter()
-                self.params, self.opt_state, metrics = self.step_fn(
-                    self.params, self.opt_state, batch)
-                metrics = jax.device_get(metrics)
-                dt = time.perf_counter() - t0
-            straggler = self.detector.observe(self.step, dt)
-            rec = {"step": self.step, "time_s": dt,
-                   "loss": float(metrics["loss"]),
-                   "n_devices": self.plan.n_devices,
-                   "straggler_flag": straggler}
-            self.log.append(rec)
-            self._emit_telemetry(dt, t_data)
-            self.step += 1
-            if self.step % self.checkpoint_every == 0:
-                self.ckpt.save(self.step, {
-                    "params": self.params, "opt": self.opt_state})
+            with jax.profiler.StepTraceAnnotation(trace.STEP,
+                                                  step_num=self.step):
+                self._step()
         # saves stay in flight: joining here would put checkpoint I/O on
         # the critical path of callers stepping one step at a time (the
         # manager.Controller loop).  save()/restore() already serialize
         # against the in-flight write; call ckpt.wait() for durability.
         return self.log
+
+    def _step(self) -> None:
+        """One step, each phase under its own span.  ``time_s`` is dispatch
+        plus ``device_get``, as the detector and the controller read it;
+        ``data_s`` is the input pipeline's wait.  The counters cover the
+        whole step, from the batch to a due checkpoint; they are the
+        process's, so a compile or a collection on another thread while the
+        step runs is counted in it."""
+        before = trace.counters()
+        with trace.Span(trace.DATA) as data:
+            batch = self.data.batch(self.step)
+        with jax.set_mesh(self.mesh):
+            with trace.Span(trace.DISPATCH) as dispatch:
+                self.params, self.opt_state, metrics = self.step_fn(
+                    self.params, self.opt_state, batch)
+            with trace.Span(trace.SYNC) as sync:
+                metrics = jax.device_get(metrics)
+        with trace.Span(trace.LOG):
+            dt = dispatch.seconds + sync.seconds
+            rec = {"step": self.step, "time_s": dt,
+                   "data_s": data.seconds, "dispatch_s": dispatch.seconds,
+                   "sync_s": sync.seconds,
+                   "loss": float(metrics["loss"]),
+                   "n_devices": self.plan.n_devices,
+                   "straggler_flag": self.detector.observe(self.step, dt)}
+            self.log.append(rec)
+            self._emit_telemetry(dt, data.seconds)
+        self.step += 1
+        if self.step % self.checkpoint_every == 0:
+            with trace.Span(trace.CHECKPOINT):
+                self.ckpt.save(self.step, {
+                    "params": self.params, "opt": self.opt_state})
+        rec.update(trace.since(before))

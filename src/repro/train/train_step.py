@@ -20,6 +20,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.dist import sharding as shd
 from repro.models import model as model_lib
 from repro.models.config import ModelConfig
+from repro.telemetry import trace
 from repro.train import optimizer as opt_lib
 
 
@@ -90,8 +91,9 @@ def make_train_step(cfg: ModelConfig, opt_cfg: opt_lib.OptimizerConfig,
     def train_step(params, opt_state, batch):
         loss, grads = loss_and_grads(cfg, params, batch, mesh,
                                      micro_weights=micro_weights)
-        params, opt_state, om = opt_lib.apply_updates(
-            params, grads, opt_state, opt_cfg)
+        with jax.named_scope(trace.OPTIMIZER):
+            params, opt_state, om = opt_lib.apply_updates(
+                params, grads, opt_state, opt_cfg)
         metrics = {"loss": loss, **om}
         return params, opt_state, metrics
 

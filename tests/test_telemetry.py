@@ -10,12 +10,13 @@ from repro.core.profiler.analytic import TrainJob
 from repro.manager.events import (CapacityUp, EventBus, LinkDegraded,
                                   NodeFailure, Straggler)
 from repro.manager.monitor import AvailabilityMonitor
-from repro.telemetry import (EXPECTED_VERDICT, ChaosHarness, DetectorBank,
-                             DetectorConfig, FaultInjector, FaultSpec,
-                             HeartbeatDetector, JsonlWriter, RootCauseAnalyzer,
-                             Sample, StreamDetector, TelemetryBus,
-                             degrade_link, read_jsonl)
 from repro.telemetry import rca as rca_mod
+from repro.telemetry.bus import JsonlWriter, Sample, TelemetryBus, read_jsonl
+from repro.telemetry.detectors import (DetectorBank, DetectorConfig,
+                                       HeartbeatDetector, StreamDetector)
+from repro.telemetry.faults import (EXPECTED_VERDICT, ChaosHarness,
+                                    FaultInjector, FaultSpec, degrade_link)
+from repro.telemetry.rca import RootCauseAnalyzer
 
 from tests.helpers import run_py
 
@@ -410,7 +411,7 @@ def test_pipeline_emits_telemetry():
         from repro.configs import get_config
         from repro.dist.pipeline import MPMDPipeline, even_stages
         from repro.models import model as model_lib
-        from repro.telemetry import TelemetryBus
+        from repro.telemetry.bus import TelemetryBus
         from repro.train import optimizer as opt_lib
         cfg = dataclasses.replace(get_config("smollm_360m").reduced(),
                                   n_layers=4, tie_embeddings=False)
@@ -446,7 +447,7 @@ def test_pipeline_emits_telemetry():
 def test_elastic_trainer_emits_telemetry(tmp_path):
     out = run_py(f"""
         from repro.configs import get_config
-        from repro.telemetry import TelemetryBus
+        from repro.telemetry.bus import TelemetryBus
         from repro.train.elastic import ElasticTrainer
         from repro.train import optimizer as opt_lib, data as data_lib
         cfg = get_config("smollm_360m").reduced()
@@ -481,7 +482,7 @@ def test_controller_audit_log_jsonl(tmp_path):
                                    ControllerConfig, IncrementalReplanner,
                                    ListFeed, TransitionConfig,
                                    TransitionModel)
-        from repro.telemetry import TelemetryBus, read_jsonl
+        from repro.telemetry.bus import TelemetryBus, read_jsonl
         from repro.train import data as data_lib, optimizer as opt_lib
         from repro.train.elastic import ElasticTrainer
         import os
