@@ -126,8 +126,10 @@ def train_phase(cfg, *, seq_len: int = 2048, micro_batch: int = 4,
     with jax.set_mesh(tr.mesh):
         kernel = uses_kernel(tr.step_fn.lower(tr.params, tr.opt_state,
                                               batch0))
-    check(not kernel, "the differentiated train step contains a Pallas "
-                      "kernel, which has no backward pass")
+    attention = L.pick_attn_impl(cfg.attn_impl, seq_len, True)
+    check(kernel == (attention == "pallas" and jax.default_backend() == "tpu"),
+          f"attention resolves to {attention!r} but the compiled train step "
+          f"{'contains' if kernel else 'lacks'} a Pallas kernel")
     log = tr.train(warmup + steps)
     losses = [r["loss"] for r in log]
     step_s = float(np.median([r["time_s"] for r in log[warmup:]]))
@@ -137,8 +139,7 @@ def train_phase(cfg, *, seq_len: int = 2048, micro_batch: int = 4,
             mesh="x".join(map(str, tr.plan.mesh_shape())),
             seq=seq_len, global_batch=micro_batch * num_micro,
             microbatches=num_micro,
-            attention=L.pick_attn_impl(cfg.attn_impl, seq_len, True),
-            pallas_in_step=kernel,
+            attention=attention, pallas_in_step=kernel,
             first_step_s_incl_compile=log[0]["time_s"],
             median_step_s=step_s, tokens_per_s=tokens / step_s,
             peak_bytes_in_use=peak_bytes())

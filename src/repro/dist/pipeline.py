@@ -110,11 +110,13 @@ def _slice_full_params(full: Any, stage: Stage) -> Dict[str, Any]:
     return out
 
 
-def _stage_apply(cfg: ModelConfig, stage: Stage, params, x):
+def _stage_apply(cfg: ModelConfig, stage: Stage, params, x, *,
+                 mesh: Optional[Mesh] = None):
     """Stage forward: tokens (first) or hidden states -> hidden states.
 
     Differentiated: the backward programs re-run it under ``jax.vjp``, and
-    the forward program must match them numerically."""
+    the forward program must match them numerically.  ``mesh`` is the
+    stage's own, over which the attention kernels run per shard."""
     if stage.first:
         x = params["embed"][x].astype(cfg.dtype)
     s = x.shape[1]
@@ -122,7 +124,7 @@ def _stage_apply(cfg: ModelConfig, stage: Stage, params, x):
     impl = L.pick_attn_impl(cfg.attn_impl, s, differentiated=True)
 
     def body(h, lp):
-        h, _ = transformer.attn_block(cfg, lp, h, positions, impl, None)
+        h, _ = transformer.attn_block(cfg, lp, h, positions, impl, mesh)
         h = transformer.ffn_block(cfg, lp, h, None)
         return h, None
 
@@ -133,14 +135,15 @@ def _stage_apply(cfg: ModelConfig, stage: Stage, params, x):
     return x
 
 
-def _stage_loss(cfg: ModelConfig, stage: Stage, params, x, labels):
+def _stage_loss(cfg: ModelConfig, stage: Stage, params, x, labels, *,
+                mesh: Optional[Mesh] = None):
     """Last-stage tail: layers + final norm + head + masked CE.
 
     The CE is ``models/model.py::masked_ce_sums`` — the same program as
     the single-model ``loss_fn``, so pipeline and reference losses agree
     to float32 reduction order.
     """
-    h = _stage_apply(cfg, stage, params, x)
+    h = _stage_apply(cfg, stage, params, x, mesh=mesh)
     logits = (h @ params["lm_head"].astype(cfg.dtype)).astype(jnp.float32)
     nll_sum, n_tok, _ = masked_ce_sums(logits, labels)
     return nll_sum / jnp.maximum(n_tok, 1)
@@ -280,8 +283,9 @@ class MPMDPipeline:
 
     def _build_programs(self, stage: Stage) -> Dict[str, Any]:
         cfg, opt_cfg = self.cfg, self.opt_cfg
-        apply_ = functools.partial(_stage_apply, cfg, stage)
-        loss_ = functools.partial(_stage_loss, cfg, stage)
+        mesh = self.meshes[stage.index]
+        apply_ = functools.partial(_stage_apply, cfg, stage, mesh=mesh)
+        loss_ = functools.partial(_stage_loss, cfg, stage, mesh=mesh)
 
         def fwd(p, x):
             return apply_(p, x)

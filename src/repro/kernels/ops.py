@@ -8,7 +8,8 @@ tests).  The choice follows the platform the program is lowered for, not
 the process's default backend, so a kernel is never interpreted on a TPU.
 
 Block sizes: passing explicit ints pins the tiling; ``None`` (default)
-uses the MXU-aligned defaults, or — when autotuning is on (the
+uses the kernel's rule on the shape (flash attention's
+``default_blocks``, 128/256 elsewhere), or — when autotuning is on (the
 ``REPRO_KERNEL_AUTOTUNE=1`` env switch or ``block=\"auto\"``) — the
 per-(op, shape, dtype, chip) winner from ``autotune.py``'s persistent
 cache.
@@ -43,10 +44,32 @@ def _tune(block: BlockArg) -> bool:
     return block == "auto" or (block is None and at.enabled())
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _flash(q, k, v, causal: bool, blocks: Tuple[int, int]):
+    return _flash_fwd(q, k, v, causal, blocks)[0]
+
+
+def _flash_fwd(q, k, v, causal, blocks):
+    o, lse = on_platform(fa.flash_attention_fwd, q, k, v, causal=causal,
+                         block_q=blocks[0], block_k=blocks[1])
+    return o, (q, k, v, o, lse)
+
+
+def _flash_bwd(causal, blocks, res, do):
+    return on_platform(fa.flash_attention_bwd, *res, do, causal=causal,
+                       block_q=blocks[0], block_k=blocks[1])
+
+
+_flash.defvjp(_flash_fwd, _flash_bwd)
+
+
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, block_q: BlockArg = None,
                     block_k: BlockArg = None) -> jax.Array:
-    """q: (B, S, H, D); k, v: (B, S, K, D) with H % K == 0 -> (B, S, H, D)."""
+    """q: (B, S, H, D); k, v: (B, S, K, D) with H % K == 0 -> (B, S, H, D).
+
+    Differentiable: the backward runs the dQ and dK/dV kernels, on the
+    forward's tiling."""
     b, s, h, d = q.shape
     sk = k.shape[1]
     kheads = k.shape[2]
@@ -60,10 +83,10 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     if _tune(block_q) or _tune(block_k):
         cfg = at.tune_flash_attention(qt, kt, vt, causal=causal)
         block_q, block_k = cfg["block_q"], cfg["block_k"]
-    bq = block_q if isinstance(block_q, int) else 128
-    bk = block_k if isinstance(block_k, int) else 128
-    o = on_platform(fa.flash_attention, qt, kt, vt, causal=causal,
-                    block_q=bq, block_k=bk)
+    bq, bk = fa.default_blocks(s, d)
+    blocks = (block_q if isinstance(block_q, int) else bq,
+              block_k if isinstance(block_k, int) else bk)
+    o = _flash(qt, kt, vt, causal, blocks)
     return o.reshape(b, h, s, d).transpose(0, 2, 1, 3)
 
 

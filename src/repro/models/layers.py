@@ -8,7 +8,9 @@ Attention comes in three selectable implementations:
   window   sliding-window attention that is *linear* in sequence length: a
            scan over query blocks each attending to a dynamic KV slice of
            window+block tokens (mixtral SWA / long-context prefill).
-  pallas   the TPU kernel in repro.kernels, forward only (no backward pass).
+  pallas   the TPU flash-attention kernels in repro.kernels, forward and
+           backward (a custom VJP): what prefill, and training from seq
+           1024 up, run on a TPU (``pick_attn_impl``).
 
 All softmax statistics are computed in float32 regardless of input dtype.
 """
@@ -232,23 +234,42 @@ def attn_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array, *,
     return o.reshape(b, 1, h, hd).astype(q.dtype)
 
 
+def _flash_attention(q, k, v, causal: bool, mesh) -> jax.Array:
+    """The Pallas kernels, forward and backward.  A Mosaic kernel cannot be
+    partitioned by XLA, so on a mesh of several devices each runs on its
+    shard: batch over the data axes, heads over ``model`` where they
+    divide (else replicated, each device computing them whole)."""
+    from repro.kernels import ops as kops
+    fn = functools.partial(kops.flash_attention, causal=causal)
+    if mesh is None or mesh.size == 1:
+        return fn(q, k, v)
+    from jax.sharding import PartitionSpec as P
+    from repro.dist.sharding import batch_spec
+    tp = dict(mesh.shape).get("model", 1)
+    heads = ("model" if tp > 1 and q.shape[2] % tp == 0
+             and k.shape[2] % tp == 0 else None)
+    spec = P(batch_spec(mesh, q.shape[0])[0], None, heads, None)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)
+
+
 def attention(q, k, v, *, impl: str = "chunked", causal: bool = True,
               window: int = 0, q_pos=None, k_pos=None,
               kv_len=None, block: int = 1024,
-              block_remat: bool = False) -> jax.Array:
-    """Dispatch over implementations; q_pos/k_pos default to arange."""
+              block_remat: bool = False, mesh=None) -> jax.Array:
+    """Dispatch over implementations; q_pos/k_pos default to arange.
+    ``mesh``: the mesh the caller's arrays are sharded over, if any."""
     if q_pos is None:
         q_pos = jnp.arange(q.shape[1])
     if k_pos is None:
         k_pos = jnp.arange(k.shape[1])
     if impl == "pallas":
-        from repro.kernels import ops as kops
         # the kernel handles causal/non-causal and non-divisible (even
         # unequal) sequence lengths via internal pad+mask; only window
         # and explicit kv_len masking still route to the jnp fallback
         if window == 0 and kv_len is None and (
                 not causal or q.shape[1] == k.shape[1]):
-            return kops.flash_attention(q, k, v, causal=causal)
+            return _flash_attention(q, k, v, causal, mesh)
         impl = "chunked"
     if impl == "window" or (window > 0 and causal and q.shape[1] > window
                             and impl != "naive" and kv_len is None):
@@ -261,24 +282,27 @@ def attention(q, k, v, *, impl: str = "chunked", causal: bool = True,
                         block_remat=block_remat)
 
 
+# Differentiated callers shorter than this keep XLA attention on a TPU: on
+# a v5e at head dim 64 (4096 tokens a call), naive attention's forward and
+# backward took 0.56 ms against the flash kernels' 0.91 at S=512, and 2.04
+# against 1.18 at S=1024 (PERF.md, section 6).
+TRAIN_KERNEL_MIN_SEQ = 1024
+
+
 def pick_attn_impl(cfg_impl: str, seq_len: int, differentiated: bool,
                    backend: Optional[str] = None) -> str:
     """Resolve the attention implementation for one call site.
 
-    The Pallas kernels have no backward pass, so a differentiated caller
-    (the training loss, a pipeline stage under ``jax.vjp``) always runs
-    XLA attention, and an explicit ``"pallas"`` there is an error.
-    Forward-only callers (prefill, serving) resolve ``"auto"`` to the
-    kernel wherever it compiles to Mosaic (TPU).  Otherwise ``"auto"`` is
-    naive for short sequences and the chunked online-softmax beyond (full
-    scores don't fit)."""
-    if differentiated and cfg_impl == "pallas":
-        raise ValueError(
-            "attn_impl='pallas' on a differentiated path: the Pallas "
-            "attention kernel has no backward pass; train with 'auto', "
-            "'naive' or 'chunked'")
+    On a TPU ``"auto"`` is the Pallas kernel wherever it compiles to
+    Mosaic: for forward-only callers (prefill, serving) at any length, and
+    for differentiated ones (the training loss, a pipeline stage under
+    ``jax.vjp``), whose gradients its custom VJP computes with the flash
+    backward kernels, from ``TRAIN_KERNEL_MIN_SEQ`` up.  Otherwise
+    ``"auto"`` is naive for short sequences and the chunked online-softmax
+    beyond (full scores don't fit)."""
     if cfg_impl != "auto":
         return cfg_impl
-    if not differentiated and (backend or jax.default_backend()) == "tpu":
+    if (backend or jax.default_backend()) == "tpu" and (
+            not differentiated or seq_len >= TRAIN_KERNEL_MIN_SEQ):
         return "pallas"
     return "naive" if seq_len <= 2048 else "chunked"

@@ -12,9 +12,11 @@ Design notes
   ``dist/sharding.py``); GQA heads that don't divide the 16-way model axis
   fall back to replication automatically.
 * The same ``forward`` serves train (full seq, causal) and prefill (returns
-  the KV cache); ``decode`` runs one token against the cache.  Training
-  passes ``differentiated=True``, which keeps the forward-only Pallas
-  kernels out of the graph (``layers.pick_attn_impl``).
+  the KV cache); ``decode`` runs one token against the cache.  On a TPU
+  both run the Pallas flash-attention kernels (``layers.pick_attn_impl``),
+  whose custom VJP gives training the flash backward; training passes
+  ``differentiated=True``, which keeps the forward-only fused
+  residual-norm kernel out of the graph (``decoder_block``).
 """
 from __future__ import annotations
 
@@ -113,7 +115,7 @@ def attn_delta(cfg: ModelConfig, p, x, positions, impl: str,
     with jax.named_scope(trace.ATTENTION):
         o = L.attention(q, k, v, impl=impl, causal=True, window=cfg.window,
                         q_pos=positions, k_pos=positions,
-                        block_remat=cfg.attn_block_remat)
+                        block_remat=cfg.attn_block_remat, mesh=mesh)
     return jnp.einsum("bshk,hkd->bsd", o, p["wo"]), (k, v)
 
 
@@ -141,15 +143,16 @@ def ffn_block(cfg: ModelConfig, p, x, mesh: Optional[Mesh]):
 
 
 def decoder_block(cfg: ModelConfig, p, x, positions, impl: str,
-                  mesh: Optional[Mesh]):
+                  mesh: Optional[Mesh], differentiated: bool = False):
     """attn_block + ffn_block with the residual seam between them fused:
     the post-attention add and the FFN's pre-norm run as one Pallas pass
-    when ``impl == "pallas"`` (see kernels/fused.py); identical math on
-    the jnp path."""
+    when ``impl == "pallas"`` (see kernels/fused.py) and nothing
+    differentiates the block (that kernel has no backward); identical math
+    on the jnp path."""
     delta, kv = attn_delta(cfg, p, x, positions, impl, mesh)
     h, x = L.rms_norm_residual(
         x, delta, p["ln2"], cfg.norm_eps,
-        impl="pallas" if impl == "pallas" else "jnp")
+        impl="pallas" if impl == "pallas" and not differentiated else "jnp")
     return x + _ffn(cfg, p, h, mesh), kv
 
 
@@ -182,7 +185,8 @@ def forward(cfg: ModelConfig, params, batch: Dict[str, jax.Array], *,
         x = constrain(x, batch_spec(mesh, b, None, None))
 
     def body(x, lp):
-        x, (k, v) = decoder_block(cfg, lp, x, positions, impl, mesh)
+        x, (k, v) = decoder_block(cfg, lp, x, positions, impl, mesh,
+                                  differentiated)
         if mesh is not None:
             x = constrain(x, batch_spec(mesh, x.shape[0], None, None))
         if return_cache:

@@ -136,6 +136,52 @@ def test_sharded_train_step_matches_single_device():
     assert "OK" in out
 
 
+def test_pallas_attention_runs_per_shard():
+    """The flash kernels (interpreted here) inside a 4x2 ``fsdp_tp`` train
+    step and a 2-stage x tp=2 pipeline, each kernel on its own shard: the
+    same loss and update as naive attention on one device."""
+    out = run_py("""
+        import dataclasses, jax, numpy as np, jax.numpy as jnp
+        from repro.configs import get_config
+        from repro.dist.mesh import data_model_mesh
+        from repro.dist.pipeline import MPMDPipeline, even_stages
+        from repro.models import model as model_lib
+        from repro.train import optimizer as opt_lib
+        from repro.train.train_step import jit_train_step, make_train_step
+        cfg = dataclasses.replace(get_config("qwen1_5_0_5b").reduced(),
+                                  sharding="fsdp_tp", tie_embeddings=False)
+        kern = dataclasses.replace(cfg, attn_impl="pallas")
+        params = model_lib.init(cfg, jax.random.PRNGKey(0))
+        opt_cfg = opt_lib.OptimizerConfig(lr=1e-3)
+        opt_state = opt_lib.init_state(params)
+        rng = np.random.default_rng(0)
+        toks = rng.integers(0, cfg.vocab_size, (1, 8, 17)).astype(np.int32)
+        batch = {"tokens": jnp.asarray(toks[..., :-1]),
+                 "labels": jnp.asarray(toks[..., 1:])}
+        p1, _, m1 = jax.jit(make_train_step(
+            dataclasses.replace(cfg, attn_impl="naive"), opt_cfg))(
+            params, opt_state, batch)
+        mesh = data_model_mesh(4, 2)
+        with jax.set_mesh(mesh):
+            step = jit_train_step(kern, opt_cfg, mesh, 1, 8, donate=False)
+            p2, _, m2 = step(params, opt_state, batch)
+        assert abs(float(m1["loss"]) - float(m2["loss"])) < 1e-4
+        for a, b in zip(jax.tree_util.tree_leaves(p1),
+                        jax.tree_util.tree_leaves(p2)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=2e-3, atol=2e-4)
+        pipe = MPMDPipeline(kern, even_stages(kern, tps=[2, 2], dp=1),
+                            opt_cfg)
+        full = jax.tree_util.tree_map(jnp.asarray, pipe.full_params_like(
+            jax.device_get(params)))
+        loss_pipe = pipe.train_step(
+            {k: v.reshape(2, 4, 16) for k, v in batch.items()})
+        assert abs(float(m1["loss"]) - loss_pipe) < 1e-3, (m1, loss_pipe)
+        print("OK", float(m1["loss"]), loss_pipe)
+    """, devices=8, timeout=900)
+    assert "OK" in out
+
+
 def test_dryrun_small_mesh_cell():
     """Full dry-run path (lower+compile+analysis) on an 8-device mesh."""
     out = run_py("""

@@ -3,6 +3,8 @@
 Per the assignment: sweep shapes/dtypes and assert_allclose against the
 ref.py oracle for every kernel.
 """
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -120,6 +122,49 @@ def test_flash_attention_non_divisible(sq, sk, causal):
                                    causal=causal).transpose(0, 2, 1, 3)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
+
+
+# --- backward: dQ and dK/dV kernels through the custom VJP ----------------------
+
+def _attention_loss(fn, ct):
+    return lambda q, k, v: jnp.sum(fn(q, k, v).astype(jnp.float32) * ct)
+
+
+@pytest.mark.parametrize("blocks", [(32, 64), (64, 32)])
+@pytest.mark.parametrize("sq,sk,h,kh", [
+    (128, 128, 4, 2),          # GQA, divisible
+    (100, 100, 4, 4),          # ragged vs every block size
+    (130, 70, 4, 2),           # unequal and ragged, GQA
+])
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_flash_attention_grads(blocks, sq, sk, h, kh, causal, dtype):
+    """dQ, dK, dV of ops.flash_attention == jax.grad of the fp32 oracle
+    (GQA's repeat summed back onto the KV heads by jnp's own VJP)."""
+    bq, bk = blocks
+    q = jnp.asarray(RNG.standard_normal((2, sq, h, 64)), dtype)
+    k = jnp.asarray(RNG.standard_normal((2, sk, kh, 64)), dtype)
+    v = jnp.asarray(RNG.standard_normal((2, sk, kh, 64)), dtype)
+    ct = jnp.asarray(RNG.standard_normal((2, sq, h, 64)), jnp.float32)
+
+    def oracle(q, k, v):
+        bhsd = lambda x: jnp.repeat(x.astype(jnp.float32), h // x.shape[2],
+                                    axis=2).transpose(0, 2, 1, 3)
+        return ref.flash_attention_ref(bhsd(q), bhsd(k), bhsd(v),
+                                       causal=causal).transpose(0, 2, 1, 3)
+
+    got = jax.grad(_attention_loss(
+        lambda q, k, v: ops.flash_attention(q, k, v, causal=causal,
+                                            block_q=bq, block_k=bk), ct),
+        (0, 1, 2))(q, k, v)
+    with jax.default_matmul_precision("highest"):
+        want = jax.grad(_attention_loss(oracle, ct), (0, 1, 2))(q, k, v)
+    tol = 1e-2 if dtype == jnp.bfloat16 else 1e-5
+    for name, a, b in zip("qkv", got, want):
+        assert a.shape == b.shape and a.dtype == dtype, name
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        err = np.linalg.norm(a - b) / np.linalg.norm(b)
+        assert err <= tol, f"d{name}: relative error {err}"
 
 
 def test_ssd_non_divisible_seq():
@@ -314,29 +359,70 @@ def test_pick_attn_impl():
     assert L.pick_attn_impl("auto", 128, False, backend="tpu") == "pallas"
     assert L.pick_attn_impl("auto", 128, False, backend="cpu") == "naive"
     assert L.pick_attn_impl("auto", 8192, False, backend="cpu") == "chunked"
-    # differentiated callers never get the forward-only kernel
-    assert L.pick_attn_impl("auto", 128, True, backend="tpu") == "naive"
-    assert L.pick_attn_impl("auto", 8192, True, backend="tpu") == "chunked"
-    with pytest.raises(ValueError, match="no backward pass"):
-        L.pick_attn_impl("pallas", 128, True)
+    # differentiated callers train through the kernel's custom VJP on a
+    # TPU, from the measured threshold up; below it XLA attention is faster
+    assert L.pick_attn_impl("auto", 2048, True, backend="tpu") == "pallas"
+    assert L.pick_attn_impl("auto", 8192, True, backend="tpu") == "pallas"
+    assert L.TRAIN_KERNEL_MIN_SEQ == 1024
+    assert L.pick_attn_impl("auto", 1024, True, backend="tpu") == "pallas"
+    assert L.pick_attn_impl("auto", 512, True, backend="tpu") == "naive"
+    assert L.pick_attn_impl("auto", 512, False, backend="tpu") == "pallas"
+    assert L.pick_attn_impl("auto", 2048, True, backend="cpu") == "naive"
+    assert L.pick_attn_impl("pallas", 128, True) == "pallas"
+
+
+def _qwen_like(**kw):
+    """A reduced Qwen-architecture config (QKV bias, SwiGLU, RMSNorm,
+    rotary, tied head) at head dim 64."""
+    from repro.configs import get_config
+    return dataclasses.replace(get_config("qwen1_5_0_5b").reduced(),
+                               d_model=128, n_heads=2, n_kv_heads=2,
+                               head_dim=64, **kw)
+
+
+def _batch(cfg, b, s):
+    toks = jnp.asarray(RNG.integers(0, cfg.vocab_size, (b, s + 1)),
+                       jnp.int32)
+    return {"tokens": toks[:, :-1], "labels": toks[:, 1:]}
 
 
 def test_loss_fn_rejects_pallas_attention():
-    """An explicit Pallas attention on the training path is a readable
-    error at trace time, not the Pallas JVP assertion."""
-    import dataclasses
-    from repro.configs import get_config
+    """An explicit Pallas attention on the training path (once refused,
+    for want of a backward) runs: the step's loss and gradients are
+    finite, and its forward still matches the prefill's, which runs the
+    same kernel."""
     from repro.models import model as model_lib
-    cfg = dataclasses.replace(get_config("opt_350m").reduced(),
-                              attn_impl="pallas")
+    cfg = _qwen_like(attn_impl="pallas")
     params = model_lib.init(cfg, jax.random.PRNGKey(0))
-    toks = jnp.asarray(RNG.integers(0, cfg.vocab_size, (2, 17)), jnp.int32)
-    batch = {"tokens": toks[:, :-1], "labels": toks[:, 1:]}
-    with pytest.raises(ValueError, match="no backward pass"):
-        jax.grad(lambda p: model_lib.loss_fn(cfg, p, batch)[0])(params)
-    # the forward-only path (prefill) still runs the kernel
-    logits = model_lib.forward(cfg, params, batch)
-    assert bool(jnp.all(jnp.isfinite(logits)))
+    batch = _batch(cfg, 2, 40)
+    (loss, _), grads = jax.value_and_grad(
+        lambda p: model_lib.loss_fn(cfg, p, batch), has_aux=True)(params)
+    assert bool(jnp.isfinite(loss))
+    assert all(bool(jnp.all(jnp.isfinite(g))) for g in jax.tree.leaves(grads))
+    train_logits = model_lib.forward(cfg, params, batch, differentiated=True)
+    prefill_logits = model_lib.forward(cfg, params, batch)
+    np.testing.assert_allclose(np.asarray(train_logits),
+                               np.asarray(prefill_logits),
+                               rtol=2e-5, atol=2e-5)
+
+
+def test_loss_fn_grads_pallas_match_naive():
+    """The training loss of a Qwen-architecture model has the same value
+    and gradients through the Pallas kernels as through naive attention."""
+    from repro.models import model as model_lib
+    cfg = _qwen_like(remat="full")
+    params = model_lib.init(cfg, jax.random.PRNGKey(1))
+    batch = _batch(cfg, 2, 96)
+    out = {}
+    for impl in ("pallas", "naive"):
+        c = dataclasses.replace(cfg, attn_impl=impl)
+        out[impl] = jax.value_and_grad(
+            lambda p: model_lib.loss_fn(c, p, batch)[0])(params)
+    (lp, gp), (ln, gn) = out["pallas"], out["naive"]
+    np.testing.assert_allclose(float(lp), float(ln), rtol=1e-5)
+    for a, b in zip(jax.tree.leaves(gp), jax.tree.leaves(gn)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=1e-6)
 
 
 def test_attn_decode_pallas_impl():
