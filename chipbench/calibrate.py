@@ -8,7 +8,8 @@ For each seed of ``--seeds``: the program's first three steps, as a run of
 the benchmark makes them, against the reference (the lower readings).  For
 each seed of ``--control-seeds`` also, each against the same reference:
 
-- ``control``: the reference computed in float8 (``reference.py``);
+- ``control``: the reference computed in float8 (the configuration's
+  reference module);
 - ``half_batch``: the reference with half of the batch left out, its mean
   taken over the rest;
 - ``no_exchange``: the reference with the gradient of the first data
@@ -67,8 +68,7 @@ def main(argv=None) -> int:
     kinds = ["control", "half_batch"] + (["no_exchange"]
                                          if w["chips"] > 1 else [])
     for seed in sorted(set(seeds) | set(ctrl)):
-        r = spec["kind"].Run(spec["config"], spec["traffic"], spec["cell"],
-                               w["chips"], seed)
+        r = bench.make_run(spec, seed)
         t = time.perf_counter()
         r.setup()
         t_prog = time.perf_counter() - t

@@ -1,4 +1,5 @@
-"""Model FLOPs of one trained token of a dense decoder-only transformer.
+"""Model FLOPs of one trained token of a dense decoder-only transformer,
+and the work of one call of each flash-attention kernel.
 
 The PaLM convention (Chowdhery et al. 2022, appendix B):
 
@@ -34,3 +35,35 @@ def params(model: Dict[str, Any]) -> int:
 def flops_per_token(model: Dict[str, Any], seq_len: int) -> float:
     d_attn = model["n_heads"] * model["head_dim"]
     return 6.0 * params(model) + 12.0 * model["n_layers"] * d_attn * seq_len
+
+
+def attention_kernels(model: Dict[str, Any], traffic: Dict[str, Any]
+                      ) -> Dict[str, Dict[str, float]]:
+    """FLOPs and bytes of one call of each causal flash-attention kernel
+    (``fwd``, the backward's ``dq`` and ``dkv``) at the traffic's
+    microbatch: ``rows = global_batch / num_micro`` rows of ``n_heads``
+    heads (key and value heads repeated to as many), each of ``seq_len``
+    positions and ``head_dim`` wide.
+
+    The work is that of the algorithm, over half the ``S x S`` scores (the
+    causal triangle), per row and head:
+
+    - ``fwd``: Q K^T and P V, ``4 S^2 d / 2``; full remat's recompute of
+      the forward is a call of its own;
+    - ``dq``: Q K^T again (for P), dO V^T and dS K, ``6 S^2 d / 2``;
+    - ``dkv``: Q K^T, dO V^T, P^T dO and dS^T Q, ``8 S^2 d / 2``.
+
+    Bytes are what each kernel reads and writes once: Q, K, V, O, dO, dQ,
+    dK, dV in the activations' dtype, and the float32 log-sum-exp ``lse``
+    and ``di = rowsum(dO * O)``, one number per row, head and position.
+    ``fwd`` reads Q, K, V and writes O and ``lse``; ``dq`` reads Q, K, V,
+    dO, ``lse`` and ``di`` and writes dQ; ``dkv`` reads the same and writes
+    dK and dV."""
+    s, d = traffic["seq_len"], model["head_dim"]
+    bh = traffic["global_batch"] // traffic["num_micro"] * model["n_heads"]
+    half = bh * s * s * d / 2
+    act = bh * s * d * (2 if model["dtype"] in ("bfloat16", "float16") else 4)
+    row = bh * s * 4
+    return {"fwd": {"flops": 4 * half, "bytes": 4 * act + row},
+            "dq": {"flops": 6 * half, "bytes": 5 * act + 2 * row},
+            "dkv": {"flops": 8 * half, "bytes": 6 * act + 2 * row}}

@@ -11,17 +11,24 @@ drawn from a Zipf law over the vocabulary, as words of text are, and
 every row of every step differs.  The cell file gives the mesh
 (``dp`` x ``tp``), the reference's ``block_rows`` and the limits.
 
-Set-up makes the weights on the device from the seed in one jitted call,
-puts them in the trainer's place, and runs the first three steps, which
-also compile the step.  The reference then follows those three steps
-(``reference.run_steps``) once the window has closed and the trainer's
-state is freed.
+The run is handed the configuration's own reference module (the one its
+configuration file names), and imports none itself.  That module gives
+``seed_key``, ``init_params``, ``param_shapes`` and ``run_steps``.
+
+Set-up makes the weights on the device from the seed in one jitted call
+(the reference's ``init_params``, in the trainer's own layout, which must
+be the reference's), puts them in the trainer's place, and runs the first
+three steps, which also compile the step.  The reference then follows
+those three steps (``run_steps``) once the window has closed and the
+trainer's state is freed.
 """
 from __future__ import annotations
 
 import gc
+import json
 import math
 import shutil
+import sys
 import tempfile
 import time
 from typing import Any, Dict, List, Optional, Sequence
@@ -32,7 +39,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 import compare
-import reference
 
 CHECKED_STEPS = 3
 SPAN = "chipbench.train_call"
@@ -68,6 +74,17 @@ def _minus(a, b, shard=None):
         lambda x, y: x.astype(jnp.float32) - y.astype(jnp.float32), a, b)
 
 
+def slowest(window: Sequence[Dict[str, Any]]) -> Dict[str, Any]:
+    """The window's compiles and Python collections, and the phases of its
+    slowest step, from the trainer's records of its steps."""
+    worst = max(window, key=lambda r: r["time_s"])
+    return {"window_compiles": sum(r["compiles"] for r in window),
+            "window_gc_collections": sum(r["gc_collections"] for r in window),
+            "slowest_step": {k: worst[k] for k in (
+                "step", "time_s", "data_s", "dispatch_s", "sync_s",
+                "compiles", "compile_s", "gc_collections", "gc_s")}}
+
+
 def _program_opt(opt_cfg) -> Dict[str, Any]:
     return {k: getattr(opt_cfg, k) for k in (
         "lr", "beta1", "beta2", "eps", "weight_decay", "warmup_steps",
@@ -76,7 +93,7 @@ def _program_opt(opt_cfg) -> Dict[str, Any]:
 
 class Run:
     def __init__(self, config: Dict[str, Any], traffic: Dict[str, Any],
-                 cell: Dict[str, Any], chips: int, seed: int):
+                 cell: Dict[str, Any], chips: int, seed: int, ref):
         self.model = dict(config["model"])
         self.train_cfg = dict(config["training"])
         self.ref_model = dict(self.model,
@@ -86,6 +103,7 @@ class Run:
         self.tokens_per_step = traffic["global_batch"] * traffic["seq_len"]
         self.feed = Feed(traffic, self.model["vocab_size"], seed)
         self.workdir = tempfile.mkdtemp(prefix="chipbench-")
+        self.ref = ref
         self.tr = None
         self.prog: Dict[str, Any] = {}
 
@@ -114,8 +132,8 @@ class Run:
                              f"is not the configuration's {want_opt}")
         # the benchmark's weights from the seed, drawn once, in the
         # trainer's own layout; ``build`` then finds them in place
-        key = reference.seed_key(self.seed)
-        draw = lambda k: reference.init_params(self.ref_model, k)  # noqa: E731
+        key = self.ref.seed_key(self.seed)
+        draw = lambda k: self.ref.init_params(self.ref_model, k)  # noqa: E731
         layout = lambda f: jax.tree.map(  # noqa: E731
             lambda a: (a.shape, a.dtype), jax.eval_shape(f, key))
         if layout(lambda k: model_lib.init(tr.cfg, k)) != layout(draw):
@@ -165,6 +183,7 @@ class Run:
         # the trainer's own time of each step (dispatch to its metrics on
         # the host), to tell a slow step from slow host work between steps
         step_s = [r["time_s"] for r in tr.log[n0:]]
+        print(json.dumps(slowest(tr.log[n0:])), file=sys.stderr, flush=True)
         return {"steps": steps, "seconds": dt,
                 "failed": sum(not math.isfinite(x) for x in losses),
                 "train_tokens_per_s": steps * self.tokens_per_step / dt,
@@ -182,6 +201,20 @@ class Run:
                     tr.train(1)
                 n += 1
             jax.block_until_ready(tr.params)
+
+    def step_hlo(self) -> str:
+        """The compiled step's HLO text, whose op_name paths hold the
+        program's scopes.  Lowered with the window's own shapes, so JAX's
+        in-process cache hands back the executable that the window ran and
+        nothing compiles.  Where something would, the persistent cache is
+        off: its key leaves the scope metadata out, so it could hand back
+        an executable whose HLO lacks them, or has older ones."""
+        from repro.launch.compile_cache import persistent_cache_disabled
+        tr = self.tr
+        batch = self.feed.batch(tr.step)
+        with jax.set_mesh(tr.mesh), persistent_cache_disabled():
+            return tr.step_fn.lower(tr.params, tr.opt_state,
+                                    batch).compile().as_text()
 
     def simulated(self, peak_bytes: Sequence[int], step_s: float) -> Dict:
         """What the planner's simulator predicts for this cell's plan."""
@@ -235,7 +268,7 @@ class Run:
                     return NamedSharding(mesh, P(*([None] * ax + ["x"])))
             return NamedSharding(mesh, P())
 
-        shapes = reference.param_shapes(self.model)
+        shapes = self.ref.param_shapes(self.model)
         shard = jax.tree.map(spec, shapes,
                              is_leaf=lambda s: isinstance(s, tuple))
         return shard, NamedSharding(mesh, P("x")), self.cell["block_rows"]
@@ -259,18 +292,18 @@ class Run:
             loss_rows = range(n_rows)
         elif fault is not None:
             raise ValueError(f"unknown fault {fault!r}")
-        out = reference.run_steps(
+        out = self.ref.run_steps(
             self.ref_model, self.train_cfg, self.seed, self.batches(),
             precision=precision, block_rows=block, sharding=shard,
             row_sharding=rows_shard, rows=rows, loss_rows=loss_rows)
         with jax.default_matmul_precision("highest"):
             norms = jax.jit(compare.leaf_norms)
             change = jax.jit(lambda p, k: compare.leaf_norms(_minus(
-                p, reference.init_params(self.ref_model, k), shard)))
+                p, self.ref.init_params(self.ref_model, k), shard)))
             res = {"losses": out["losses"], "grads": out["grads"],
                    "grad_norms": jax.device_get(norms(out["grads"])),
                    "update_norms": jax.device_get(change(
-                       out["params"], reference.seed_key(self.seed)))}
+                       out["params"], self.ref.seed_key(self.seed)))}
         del out
         gc.collect()
         return res

@@ -1,9 +1,10 @@
 """``mfu.train``: the whole training step's share of the chips' peak.
 
-Model FLOPs per token (``flops/<family>.py``, no recomputation counted)
-times the window's trained tokens per second, over the chips' summed
-bf16 peak from ``peaks.json``, in percent.  Read from the untraced window
-of the run, so the profiler's own cost is not in it.
+Model FLOPs per token (``flops_per_token`` of the configuration's FLOP
+module, no recomputation counted) times the window's trained tokens per
+second, over the chips' summed bf16 peak from ``peaks.json``, in percent.
+Read from the untraced window of the run, so the profiler's own cost is
+not in it.
 """
 
 
@@ -12,4 +13,6 @@ def read(ctx):
     if not rate:
         return None
     peak = ctx["chips"] * ctx["peak"]["bf16_flops_per_s"]
-    return 100.0 * ctx["flops_per_token"] * rate / peak
+    per_token = ctx["flops"].flops_per_token(ctx["config"]["model"],
+                                             ctx["traffic"]["seq_len"])
+    return 100.0 * per_token * rate / peak

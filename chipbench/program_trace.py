@@ -1,10 +1,10 @@
-#!/usr/bin/env python3
 """The program's own names in a profiler trace, and the per-layer numbers
 read from them.
 
-``trace_reduce.load`` keeps the device operations under XLA's names and the
-benchmark's ``chipbench.`` host spans.  ``load`` here keeps two more
-things, under keys of their own, and leaves the others as they are:
+``load`` reads the ``.xplane.pb`` that ``jax.profiler`` writes into the
+reduced form of ``trace_reduce`` (the device operations under XLA's names,
+the benchmark's ``chipbench.`` host spans, the window), and keeps three
+more things, under keys of their own:
 
 - ``program_spans``: the program's host spans (names that start with
   ``PROGRAM_PREFIX``, such as ``repro.train.data``) as [name, start,
@@ -15,19 +15,16 @@ things, under keys of their own, and leaves the others as they are:
   ``repro.loss``, ``repro.optimizer``) and the phase it ran in.  A v5e
   trace's op events carry no such path, so it comes from the HLO text of
   the compiled step (``hlo_scopes``), whose instruction names are the
-  trace's op names.
+  trace's op names;
+- ``kernels``: for each device operation that is a Mosaic kernel (a
+  ``tpu_custom_call`` in the HLO), the shapes of its outputs, which tell
+  apart kernels that one jitted function runs under one path (the flash
+  backward's dQ kernel returns one array, its dK/dV kernel two).
 
 A reduced trace without these keys, or whose paths hold none of the
 scopes, still loads, and gives no value for the numbers that need them.
-
-    python3 chipbench/program_trace.py --workload <cell> --seed <n> \
-        --seconds <s> --out <file.json.gz>
-
-sets the cell up as ``run.py`` does, times an untraced window, then runs
-the traced stretch and its reduction, saves the reduced trace to ``--out``
-and prints the numbers below, the breakdown and each step's phases.  It
-stands in for ``run.py --trace 1`` until that reads ``load``; then only
-the reduction and the readers stay.
+``run.py --trace 1`` reduces its trace with ``load``, given the HLO text of
+the step that the window ran (``Run.step_hlo``).
 """
 from __future__ import annotations
 
@@ -35,7 +32,7 @@ import bisect
 import re
 import sys
 from pathlib import Path
-from typing import Callable, Dict, List, Optional
+from typing import Dict, List, Optional
 
 HERE = Path(__file__).resolve().parent
 sys.path.insert(0, str(HERE))
@@ -54,6 +51,8 @@ _OP_NAME = re.compile(r'op_name="([^"]*)"')
 _CALLS = re.compile(r"(?:calls|to_apply)=%?([\w.\-]+)")
 _COMPUTATION = re.compile(r"^\s*(?:ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$")
 _OPERANDS = re.compile(r"%([\w.\-]+)")
+_KERNEL = 'custom_call_target="tpu_custom_call"'
+_SHAPE = re.compile(r"\w+\[([\d,]*)\]")
 
 
 # --- reduction -----------------------------------------------------------------------
@@ -108,9 +107,25 @@ def hlo_scopes(hlo_text: str) -> Dict[str, str]:
     return {n: p for n in operands if (p := path(n)) is not None}
 
 
+def hlo_kernels(hlo_text: str) -> Dict[str, List[List[int]]]:
+    """Each Mosaic kernel call's output shapes, from a compiled program's
+    HLO text: ``{"flash_attention_bwd.21": [[32, 2048, 64]]}``."""
+    out = {}
+    for line in hlo_text.splitlines():
+        m = _INSTR.match(line)
+        if not m or _KERNEL not in line:
+            continue
+        name, rest = m.groups()
+        result = rest.split(" custom-call(", 1)[0]
+        out[name] = [[int(n) for n in dims.split(",") if n]
+                     for dims in _SHAPE.findall(result)]
+    return out
+
+
 def load(xplane_path: str, hlo_text: Optional[str] = None) -> Dict:
-    """``trace_reduce.load``'s reduced trace, with ``program_spans`` and
-    ``scopes`` added, in one pass over the trace."""
+    """The trace at ``xplane_path``, reduced (``trace_reduce.reduced``),
+    with ``program_spans``, ``scopes`` and ``kernels`` added, in one pass
+    over the trace."""
     from jax.profiler import ProfileData
     devices: Dict[str, List] = {}
     spans: List = []
@@ -131,9 +146,11 @@ def load(xplane_path: str, hlo_text: Optional[str] = None) -> Dict:
                         program.append([e.name, e.start_ns, e.duration_ns])
     red = trace_reduce.reduced(devices, spans)
     scopes = hlo_scopes(hlo_text) if hlo_text else {}
+    kernels = hlo_kernels(hlo_text) if hlo_text else {}
     ops = {n for evs in red["devices"].values() for n, _, _ in evs}
     red["program_spans"] = sorted(program, key=lambda s: s[1])
     red["scopes"] = {n: p for n, p in scopes.items() if n in ops}
+    red["kernels"] = {n: k for n, k in kernels.items() if n in ops}
     return red
 
 
@@ -198,12 +215,13 @@ def top_ops(red: Dict, n: int = 10) -> List[List]:
 
 
 def idle_gaps(red: Dict, n: int = 10) -> List[List]:
-    """``trace_reduce.idle_gaps``, with each gap cut where a program span
-    starts or ends, so that a piece lies in one phase of the host's step,
-    and each piece named by the innermost span of the benchmark's or the
-    program's around its middle.  Uncut, a gap between two steps (the end
-    of one step's ``device_get``, its log, the next batch and dispatch)
-    took the name of whatever its middle fell in."""
+    """The longest stretches in which a device ran nothing, as [name,
+    seconds].  Each gap is cut where a program span starts or ends, so
+    that a piece lies in one phase of the host's step, and each piece is
+    named by the innermost span of the benchmark's or the program's around
+    its middle.  Uncut, a gap between two steps (the end of one step's
+    ``device_get``, its log, the next batch and dispatch) took the name of
+    whatever its middle fell in."""
     cuts = sorted({t for _, s, d in red.get("program_spans", ())
                    for t in (s, s + d)})
     lo, hi = red["window"]
@@ -235,114 +253,18 @@ def scoped(red: Dict) -> bool:
     return any(scope(p) != "rest" for p in red.get("scopes", {}).values())
 
 
-def _per_step_ms(pattern: str) -> Callable[[Dict], Optional[float]]:
-    def read(red: Dict) -> Optional[float]:
-        if not scoped(red) or not steps(red):
-            return None
-        return 1e3 * scope_s(red, pattern) / steps(red)
-    return read
+def per_step_ms(red: Dict, pattern: str) -> Optional[float]:
+    """Device milliseconds a step of the ops whose path matches
+    ``pattern``; None where the paths hold none of the program's scopes or
+    no trainer call ran."""
+    if not scoped(red) or not steps(red):
+        return None
+    return 1e3 * scope_s(red, pattern) / steps(red)
 
 
-def _data_wait_share(red: Dict) -> Optional[float]:
+def data_wait_share(red: Dict) -> Optional[float]:
+    """The share of the window spent in the program's ``repro.train.data``
+    span, in percent; None where the trace has none of its spans."""
     if not red.get("program_spans"):
         return None
     return 100.0 * span_s(red, DATA_SPAN) / trace_reduce.window_s(red)
-
-
-# metric name -> reader of a reduced trace; None where it has nothing to read
-METRICS: Dict[str, Callable[[Dict], Optional[float]]] = {
-    "attention_ms.train": _per_step_ms(r"repro\.attention"),
-    "mlp_ms.train": _per_step_ms(r"repro\.mlp"),
-    "head_loss_ms.train": _per_step_ms(r"repro\.(head|loss)"),
-    "optimizer_ms.train": _per_step_ms(r"repro\.optimizer"),
-    "recompute_ms.train": _per_step_ms(r"rematted_computation"),
-    "data_wait_share.train": _data_wait_share,
-}
-
-
-# --- a recording on the chip -------------------------------------------------------
-
-def main(argv: Optional[List[str]] = None) -> int:
-    import argparse
-    import glob
-    import json
-    import os
-    import shutil
-    import statistics
-    import tempfile
-
-    import run as bench
-
-    ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
-    ap.add_argument("--workload", required=True)
-    ap.add_argument("--seed", type=int, required=True)
-    ap.add_argument("--seconds", type=float, required=True)
-    ap.add_argument("--out", required=True)
-    args = ap.parse_args(argv)
-    out = lambda d: print(json.dumps(d), flush=True)  # noqa: E731
-
-    spec = bench.resolve(args.workload)
-    w = spec["workload"]
-    os.environ["REPRO_KERNEL_AUTOTUNE"] = "0"
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    import jax
-    from repro.launch.compile_cache import (enable_compile_cache,
-                                            persistent_cache_disabled)
-    enable_compile_cache()
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    bench.device_check(w["chips"], spec["peaks"])
-    run = spec["kind"].Run(spec["config"], spec["traffic"], spec["cell"],
-                             w["chips"], args.seed)
-    run.setup()
-    tr = run.tr
-    n0 = len(tr.log)
-    res = run.window(args.seconds)
-    window = tr.log[n0:]
-    slowest = max(window, key=lambda r: r["time_s"])
-    out({"window": res,
-         "window_compiles": sum(r["compiles"] for r in window),
-         "window_gc_collections": sum(r["gc_collections"] for r in window),
-         "slowest_step": {k: slowest[k] for k in (
-             "step", "time_s", "data_s", "dispatch_s", "sync_s", "compiles",
-             "compile_s", "gc_collections", "gc_s")}})
-
-    d = tempfile.mkdtemp(prefix="chipbench-trace-")
-    try:
-        n1 = len(tr.log)
-        jax.profiler.start_trace(d)
-        try:
-            run.traced(bench.TRACE_SECONDS, bench.TRACE_CALLS)
-        finally:
-            jax.profiler.stop_trace()
-        traced_steps = tr.log[n1:]
-        batch = run.feed.batch(tr.step)
-        # compiled afresh: the cache could hand back an executable whose
-        # HLO lacks the scopes, or has older ones
-        with jax.set_mesh(tr.mesh), persistent_cache_disabled():
-            hlo = tr.step_fn.lower(tr.params, tr.opt_state,
-                                   batch).compile().as_text()
-        files = glob.glob(f"{d}/**/*.xplane.pb", recursive=True)
-        red = load(files[0], hlo)
-    finally:
-        shutil.rmtree(d, ignore_errors=True)
-    trace_reduce.save(red, args.out)
-    busy = statistics.fmean(trace_reduce.busy_s(red).values() or [0.0])
-    k = steps(red)
-    by_scope = {s: 1e3 * scope_s(red, re.escape(s)) / k for s in SCOPES}
-    ops_ms = 1e3 * sum(t for _, t in trace_reduce.top_ops(red, 10 ** 6)) / k
-    by_scope["rest"] = ops_ms - sum(by_scope.values())
-    out({"metrics": {m: f(red) for m, f in METRICS.items()},
-         "steps": k, "busy_ms_per_step": 1e3 * busy / k,
-         "window_s": trace_reduce.window_s(red),
-         "scope_ms_per_step": by_scope,
-         "program_span_s": {n: span_s(red, n) for n in sorted(
-             {s[0] for s in red["program_spans"]})},
-         "traced_step_s": [r["time_s"] for r in traced_steps],
-         "untraced_step_s_median": res["step_s_median"],
-         "scoped_ops": len(red["scopes"]),
-         "breakdown": {"device_ops": top_ops(red), "idle_gaps": idle_gaps(red)}})
-    return 0
-
-
-if __name__ == "__main__":
-    sys.exit(main())

@@ -1,4 +1,12 @@
-"""Plain float32 reference of the dense decoder and its AdamW step.
+"""Plain float32 reference of the dense decoder of the Qwen2 architecture
+and its AdamW step.
+
+A configuration names its reference in its own file (``"reference"``);
+this one is named by the configurations of that architecture
+(``configs/qwen1.5-0.5b.json``).  Another architecture brings a module of
+its own with the same four functions (``param_shapes``, ``seed_key``,
+``init_params``, ``run_steps``), and the benchmark runs whichever module
+the configuration names.
 
 Written from the configuration file's equations, in straightforward
 ``jax.numpy``: no kernel, no cache, no sharding rule of the program.  It

@@ -8,24 +8,31 @@ cell asks for.  Everything is found by name:
 
 - the cell in ``BENCHMARK.json``'s ``workloads``, and its own file
   ``chipbench/cells/<cell>.json`` (mesh, reference layout, limits);
-- its configuration's file, named in ``configs``;
+- its configuration's file, named in ``configs``, which in turn names
+  the configuration's plain reference (``reference``) and its FLOP count
+  (``flops``), each a module under ``chipbench/``;
 - its traffic mix ``chipbench/traffic/<traffic>.json``, whose ``kind``
   names the module that runs it, ``chipbench/kinds/<kind>.py``;
-- the FLOP count ``chipbench/flops/<family>.py`` of the model's family;
 - each per-layer metric's reader ``chipbench/metrics/<metric>.py``.
 
-An unknown name is an error.  A run that finds no TPU, fewer chips than
-the cell asks for, or a ``device_kind`` missing from ``peaks.json`` exits
-with code 1 and prints no result.
+So a new configuration brings only new files: its configuration, its
+reference and FLOP module where no existing one fits, its cells, traffic
+and readers.  An unknown name is an error.  A run that finds no TPU,
+fewer chips than the cell asks for, or a ``device_kind`` missing from
+``peaks.json`` exits with code 1 and prints no result.
 
 Set-up (counted in ``setup_s``) builds the system, makes the weights from
 the seed and runs the steps that the check follows, which also compiles
 every program the window uses.  The window then runs for ``--seconds``.
-With ``--trace 1`` a further short stretch runs under the profiler, and
-the per-layer metrics are read from it.  The program's state is then
-freed and the plain reference decides ``correct``.  The last line of
-standard output is the result, and the last lines of standard error are
-the numbers compared, each beside its limit.
+With ``--trace 1`` a further short stretch runs under the profiler; once
+peak memory has been read, the step's HLO is taken (``Run.step_hlo``),
+and the trace is reduced with the program's spans and op scopes
+(``program_trace.load``), from which the per-layer metrics are read;
+``--save-trace <file>`` keeps that reduced trace (``trace_reduce.save``).
+The program's state is then freed and the plain reference decides
+``correct``.  The last line of standard output is the result, and the
+last lines of standard error are the numbers compared, each beside its
+limit.
 """
 from __future__ import annotations
 
@@ -55,9 +62,16 @@ TRACE_SECONDS = 2.0
 TRACE_CALLS = 3
 
 
+def _shown(path: Path) -> str:
+    try:
+        return str(path.relative_to(ROOT))
+    except ValueError:
+        return str(path)
+
+
 def _json(path: Path) -> Any:
     if not path.is_file():
-        raise LookupError(f"{path.relative_to(ROOT)} does not exist")
+        raise LookupError(f"{_shown(path)} does not exist")
     return json.loads(path.read_text())
 
 
@@ -70,13 +84,22 @@ def find(entries: List[Dict], name: str, what: str) -> Dict:
 
 def load_module(path: Path):
     if not path.is_file():
-        raise LookupError(f"{path.relative_to(ROOT)} does not exist")
+        raise LookupError(f"{_shown(path)} does not exist")
     name = "chipbench_" + "".join(c if c.isalnum() else "_"
-                                  for c in str(path.relative_to(HERE)))
+                                  for c in _shown(path))
     spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def named_module(root: Path, config: Dict, key: str):
+    """The module that the configuration's file names under ``key``, a
+    path from the root of the checkout."""
+    if key not in config:
+        raise LookupError(f"configuration {config.get('name')!r} names no "
+                          f"{key!r} module")
+    return load_module(root / config[key])
 
 
 def applies(entry: Dict, cell: str) -> bool:
@@ -84,25 +107,35 @@ def applies(entry: Dict, cell: str) -> bool:
 
 
 def resolve(workload: str, root: Path = ROOT) -> Dict[str, Any]:
-    """Everything a run of ``workload`` needs, found by name."""
+    """Everything a run of ``workload`` needs, found by name in the
+    checkout at ``root``."""
+    here = root / HERE.relative_to(ROOT)
     bench = _json(root / "BENCHMARK.json")
     w = find(bench["workloads"], workload, "workload")
     c = find(bench["configs"], w["config"], "configuration")
     config = _json(root / c["file"])
-    traffic = _json(HERE / "traffic" / f"{w['traffic']}.json")
+    traffic = _json(here / "traffic" / f"{w['traffic']}.json")
     return {
         "bench": bench, "workload": w, "config": config, "traffic": traffic,
-        "cell": _json(HERE / "cells" / f"{workload}.json"),
-        "kind": load_module(HERE / "kinds" / f"{traffic['kind']}.py"),
-        "flops": load_module(HERE / "flops" /
-                             f"{config['model']['family']}.py"),
-        "peaks": _json(HERE / "peaks.json"),
+        "cell": _json(here / "cells" / f"{workload}.json"),
+        "kind": load_module(here / "kinds" / f"{traffic['kind']}.py"),
+        "reference": named_module(root, config, "reference"),
+        "flops": named_module(root, config, "flops"),
+        "peaks": _json(here / "peaks.json"),
         "end_to_end": [m for m in bench["end_to_end"]
                        if applies(m, workload)],
         "per_layer": [dict(m, reader=load_module(
-            HERE / "metrics" / f"{m['name']}.py"))
+            here / "metrics" / f"{m['name']}.py"))
             for m in bench["per_layer"] if applies(m, workload)],
     }
+
+
+def make_run(spec: Dict[str, Any], seed: int):
+    """The cell's run, built by its kind around its configuration's own
+    reference."""
+    return spec["kind"].Run(spec["config"], spec["traffic"], spec["cell"],
+                            spec["workload"]["chips"], seed,
+                            spec["reference"])
 
 
 def device_check(chips: int, peaks: Dict) -> tuple:
@@ -122,11 +155,17 @@ def device_check(chips: int, peaks: Dict) -> tuple:
     return devs, peaks["devices"][kind]
 
 
-def traced(run) -> Dict:
-    """A short stretch of the window's own calls under the profiler,
-    reduced."""
+def peak_bytes(devices) -> List[int]:
+    return [(d.memory_stats() or {}).get("peak_bytes_in_use", 0)
+            for d in devices]
+
+
+def traced(run, devices, err: Callable) -> tuple:
+    """A short stretch of the window's own calls under the profiler, then
+    each chip's peak memory, then the step's HLO, by which the trace is
+    reduced with the program's scopes: (reduced trace, peak bytes)."""
     import jax
-    import trace_reduce
+    import program_trace
     d = tempfile.mkdtemp(prefix="chipbench-trace-")
     try:
         jax.profiler.start_trace(d)
@@ -134,21 +173,32 @@ def traced(run) -> Dict:
             run.traced(TRACE_SECONDS, TRACE_CALLS)
         finally:
             jax.profiler.stop_trace()
+        peak = peak_bytes(devices)
+        t = time.perf_counter()
+        hlo = run.step_hlo()
+        err(f"chipbench: step HLO in {time.perf_counter() - t:.3f} s")
         files = glob.glob(f"{d}/**/*.xplane.pb", recursive=True)
         if len(files) != 1:
             raise RuntimeError(f"expected one trace file, found {files}")
-        return trace_reduce.load(files[0])
+        return program_trace.load(files[0], hlo), peak
     finally:
         shutil.rmtree(d, ignore_errors=True)
 
 
-def per_layer(spec: Dict, ctx: Dict) -> Dict[str, Dict]:
-    out = {}
+def per_layer(spec: Dict, ctx: Dict) -> tuple:
+    """The readings of the cell's per-layer metrics that found something
+    to read, and the notes of those that give some beside their value (a
+    reader may return ``{"value": v, <note>: ...}``)."""
+    out, notes = {}, {}
     for m in spec["per_layer"]:
         v = m["reader"].read(ctx)
+        if isinstance(v, dict):
+            v = dict(v)
+            notes[m["name"]] = v
+            v = v.pop("value")
         if v is not None:
             out[m["name"]] = {"value": v, "unit": m["unit"]}
-    return out
+    return out, notes
 
 
 def main(argv: Optional[List[str]] = None, *,
@@ -158,6 +208,8 @@ def main(argv: Optional[List[str]] = None, *,
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    ap.add_argument("--save-trace", help="with --trace 1, keep the reduced "
+                    "trace in this file (.json.gz)")
     args = ap.parse_args(argv)
     err = lambda s: print(s, file=sys.stderr, flush=True)  # noqa: E731
 
@@ -168,6 +220,7 @@ def main(argv: Optional[List[str]] = None, *,
     import jax
     from repro.launch.compile_cache import enable_compile_cache
     import compare
+    import program_trace
     import trace_reduce
     cache = enable_compile_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
@@ -176,34 +229,37 @@ def main(argv: Optional[List[str]] = None, *,
     err(f"chipbench: {w['name']} seed={args.seed} on {len(devs)} x "
         f"{devs[0].device_kind!r}, compile cache {cache}")
 
-    run = spec["kind"].Run(spec["config"], spec["traffic"], spec["cell"],
-                             w["chips"], args.seed)
+    run = make_run(spec, args.seed)
     run.setup()
     setup_s = time.perf_counter() - T0
     res = run.window(args.seconds)
     err(f"chipbench: window {res}")
-    red = traced(run) if args.trace else None
-    peak_bytes = [(d.memory_stats() or {}).get("peak_bytes_in_use", 0)
-                  for d in used]
+    if args.trace:
+        red, hbm = traced(run, used, err)
+        if args.save_trace:
+            trace_reduce.save(red, args.save_trace)
+    else:
+        hbm = peak_bytes(used)
     device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
-              "count": len(devs), "memory_peak_bytes": max(peak_bytes)}
+              "count": len(devs), "memory_peak_bytes": max(hbm)}
     result: Dict[str, Any] = {}
     if args.trace:
         print(json.dumps({"peak_bytes_in_use": {
-            str(d.id): b for d, b in zip(used, peak_bytes)}}))
+            str(d.id): b for d, b in zip(used, hbm)}}))
         if hasattr(run, "simulated"):
             print(json.dumps({"simulator_vs_chip": run.simulated(
-                peak_bytes, res["seconds"] / res["steps"])}))
+                hbm, res["seconds"] / res["steps"])}))
         busy = trace_reduce.busy_s(red)
         device["busy_s"] = statistics.fmean(busy.values()) if busy else 0.0
         device["window_s"] = trace_reduce.window_s(red)
-        model = spec["config"]["model"]
         ctx = {"result": res, "chips": w["chips"], "peak": peak, "trace": red,
-               "flops_per_token": spec["flops"].flops_per_token(
-                   model, spec["traffic"]["seq_len"])}
-        metrics = per_layer(spec, ctx)
-        result["breakdown"] = {"device_ops": trace_reduce.top_ops(red),
-                               "idle_gaps": trace_reduce.idle_gaps(red)}
+               "config": spec["config"], "traffic": spec["traffic"],
+               "cell": spec["cell"], "flops": spec["flops"]}
+        metrics, notes = per_layer(spec, ctx)
+        if notes:
+            print(json.dumps({"per_layer_notes": notes}))
+        result["breakdown"] = {"device_ops": program_trace.top_ops(red),
+                               "idle_gaps": program_trace.idle_gaps(red)}
     else:
         res["setup_s"] = setup_s
         metrics = {m["name"]: {"value": res[m["name"]], "unit": m["unit"]}

@@ -62,8 +62,7 @@ def test_run_is_correct_only_without_a_fault(cell, mesh, fault):
 @pytest.mark.parametrize("cell", CELLS)
 def test_control_fails_the_cells_limits(cell):
     spec = tiny.tiny_spec(cell)
-    r = spec["kind"].Run(spec["config"], spec["traffic"], spec["cell"],
-                           1, 4294967301)
+    r = tiny.bench.make_run(spec, 4294967301)
     ref = r.reference()
     values = compare.readings(r.reference(precision="fp8"), ref)
     checks = compare.check(values, spec["cell"]["limits"])

@@ -12,7 +12,9 @@ from pathlib import Path
 
 import pytest
 
+import program_trace as pt
 import run as bench
+import tiny
 import trace_reduce as tr
 
 BENCH = Path(bench.__file__).resolve().parent
@@ -57,6 +59,27 @@ def test_flops_per_token_values():
         pytest.approx(17.89e9, rel=1e-3)
 
 
+def test_attention_kernels_against_hand_count():
+    # one microbatch of s2048: 2 rows x 16 heads, S 2048, d 64, bfloat16
+    flops = bench.load_module(BENCH / "flops" / "dense.py")
+    traffic = json.loads((BENCH / "traffic" / "train.s2048.json").read_text())
+    got = flops.attention_kernels(_model(QWEN), traffic)
+    bh, s, d = 2 * 16, 2048, 64
+    tri = bh * s * s * d // 2                # half the scores, per S^2 d
+    act, row = bh * s * d * 2, bh * s * 4    # a bf16 (BH, S, d), an f32 row
+    assert tri == 4_294_967_296
+    assert got == {
+        "fwd": {"flops": 4 * tri, "bytes": 4 * act + row},
+        "dq": {"flops": 6 * tri, "bytes": 5 * act + 2 * row},
+        "dkv": {"flops": 8 * tri, "bytes": 6 * act + 2 * row}}
+    assert got["fwd"]["flops"] == 17_179_869_184
+    assert got["dkv"]["bytes"] == 50_855_936
+    # 192 forward calls (forward and recompute), 96 dQ and 96 dK/dV a step
+    step = (192 * got["fwd"]["flops"] + 96 * got["dq"]["flops"]
+            + 96 * got["dkv"]["flops"])
+    assert step == pytest.approx(9.07e12, rel=1e-3)
+
+
 # --- trace reduction -------------------------------------------------------------------
 
 def test_interval_arithmetic():
@@ -86,7 +109,7 @@ def test_reduction_of_a_synthetic_trace():
         {"TPU:0": 20e-9, "TPU:1": 20e-9})
     top = dict(tr.top_ops(red))
     assert top["fusion.1"] == pytest.approx((50 + 50) / 2 * 1e-9)
-    gaps = tr.idle_gaps(red)
+    gaps = pt.idle_gaps(red)
     assert gaps[0] == ["chipbench.window", pytest.approx(30e-9)]
     assert {g[0] for g in gaps} <= {"chipbench.window",
                                     "chipbench.train_call"}
@@ -121,21 +144,25 @@ def test_recorded_trace_reduction(recorded):
         pytest.approx(total, rel=1e-9)
     # one chip: no collective runs, so nothing to read
     assert tr.collective_exposed_s(red) == {}
-    gaps = tr.idle_gaps(red)
+    gaps = pt.idle_gaps(red)
     assert all(g[1] > 0 for g in gaps)
-    assert sum(g[1] for g in tr.idle_gaps(red, n=10 ** 6)) == \
+    assert sum(g[1] for g in pt.idle_gaps(red, n=10 ** 6)) == \
         pytest.approx(win - busy, rel=1e-6)
 
 
 def test_metric_readers_on_recorded_trace(recorded):
+    spec = bench.resolve(QWEN + ".train.s512")
     ctx = {"trace": recorded, "chips": 1, "result": {"train_tokens_per_s": 1e4},
-           "peak": {"bf16_flops_per_s": 197e12}, "flops_per_token": 2.7e9}
+           "peak": {"bf16_flops_per_s": 197e12}, "config": spec["config"],
+           "traffic": spec["traffic"], "flops": spec["flops"]}
     read = lambda n: bench.load_module(BENCH / "metrics" / f"{n}.py").read(ctx)
     idle = read("device_idle_share.train")
     assert 0 <= idle < 100
     busy = tr.busy_s(recorded)["TPU:0"]
     assert idle == pytest.approx(100 * (1 - busy / tr.window_s(recorded)))
-    assert read("mfu.train") == pytest.approx(100 * 2.7e9 * 1e4 / 197e12)
+    per_token = spec["flops"].flops_per_token(_model(QWEN), 512)
+    assert per_token == pytest.approx(2.935e9, rel=1e-3)
+    assert read("mfu.train") == pytest.approx(100 * per_token * 1e4 / 197e12)
 
 
 # --- lookup by name ---------------------------------------------------------------------
@@ -168,6 +195,96 @@ def test_unknown_names_are_errors(tmp_path):
     (root / "BENCHMARK.json").write_text(json.dumps(b))
     with pytest.raises(LookupError, match="no_such_traffic"):
         bench.resolve(b["workloads"][0]["name"], root=root)
+
+
+def test_resolve_gives_the_configurations_own_modules():
+    spec = bench.resolve(QWEN + ".train.s2048")
+    assert spec["config"]["reference"] == "chipbench/reference.py"
+    assert spec["config"]["flops"] == "chipbench/flops/dense.py"
+    assert Path(spec["reference"].__file__) == BENCH / "reference.py"
+    assert Path(spec["flops"].__file__) == BENCH / "flops" / "dense.py"
+    for f in ("seed_key", "init_params", "param_shapes", "run_steps"):
+        assert callable(getattr(spec["reference"], f))
+    assert spec["flops"].flops_per_token(spec["config"]["model"], 2048) == \
+        pytest.approx(3.388e9, rel=1e-3)
+
+
+def _checkout(tmp_path):
+    """A copy of the benchmark's files, with the configuration's file at
+    hand to change."""
+    root = tmp_path / "root"
+    shutil.copytree(BENCH, root / "chipbench", ignore=shutil.ignore_patterns(
+        "__pycache__", "tests", "testdata"))
+    shutil.copy(ROOT / "BENCHMARK.json", root)
+    return root, root / "chipbench" / "configs" / f"{QWEN}.json"
+
+
+@pytest.mark.parametrize("key", ["reference", "flops"])
+@pytest.mark.parametrize("how", ["no key", "no file"])
+def test_a_missing_reference_or_flops_is_an_error(tmp_path, key, how):
+    root, cfg_file = _checkout(tmp_path)
+    cfg = json.loads(cfg_file.read_text())
+    if how == "no key":
+        del cfg[key]
+    else:
+        cfg[key] = "chipbench/no_such_module.py"
+    cfg_file.write_text(json.dumps(cfg))
+    with pytest.raises(LookupError, match=(
+            repr(key) if how == "no key" else "no_such_module.py")):
+        bench.resolve(QWEN + ".train.s512", root=root)
+
+
+def test_a_second_configuration_needs_only_new_files(tmp_path):
+    # a second architecture, as a later PR would bring it: its own
+    # configuration file naming its own reference (here one with learned
+    # positions, whose parameters the program does not have), its own cell,
+    # and entries in BENCHMARK.json; no file that is there changes
+    root, cfg_file = _checkout(tmp_path)
+    before = {p: p.read_bytes() for p in (root / "chipbench").rglob("*")
+              if p.is_file()}
+    ref = (BENCH / "reference.py").read_text()
+    assert ref.count('"ln_f": (d,)') == 1
+    (root / "chipbench" / "reference_other.py").write_text(ref.replace(
+        '"ln_f": (d,)', '"ln_f": (d,), "wpe": (2048, d)'))
+    cfg = json.loads(cfg_file.read_text())
+    cfg.update(name="other", reference="chipbench/reference_other.py")
+    cfg["model"].update(tiny.TINY_MODEL)
+    (root / "chipbench" / "configs" / "other.json").write_text(json.dumps(cfg))
+    (root / "chipbench" / "cells" / "other.train.s512.json").write_bytes(
+        (BENCH / "cells" / f"{QWEN}.train.s512.json").read_bytes())
+    b = json.loads((root / "BENCHMARK.json").read_text())
+    b["configs"].append(dict(b["configs"][0], name="other",
+                             file="chipbench/configs/other.json"))
+    b["workloads"].append(dict(b["workloads"][1], name="other.train.s512",
+                               config="other"))
+    (root / "BENCHMARK.json").write_text(json.dumps(b))
+    assert all(p.read_bytes() == data for p, data in before.items())
+
+    spec = bench.resolve("other.train.s512", root=root)
+    assert Path(spec["reference"].__file__).name == "reference_other.py"
+    assert "wpe" in spec["reference"].param_shapes(spec["config"]["model"])
+    spec["traffic"] = dict(spec["traffic"], seq_len=tiny.TINY_SEQ)
+    run = bench.make_run(spec, 4294967301)
+    try:
+        with pytest.raises(SystemExit, match="the trainer's parameters are "
+                           "not the configuration's"):
+            run.setup()
+    finally:
+        shutil.rmtree(run.workdir, ignore_errors=True)
+
+
+def test_the_slowest_window_step_and_its_compiles():
+    kind = bench.load_module(BENCH / "kinds" / "train.py")
+    rec = lambda step, t, c, g: {  # noqa: E731
+        "step": step, "time_s": t, "data_s": 0.001, "dispatch_s": 0.002,
+        "sync_s": t - 0.002, "compiles": c, "compile_s": 0.1 * c,
+        "gc_collections": g, "gc_s": 0.01 * g, "loss": 1.0}
+    got = kind.slowest([rec(3, 0.5, 0, 1), rec(4, 2.3, 1, 0),
+                        rec(5, 0.5, 0, 2)])
+    assert got["window_compiles"] == 1
+    assert got["window_gc_collections"] == 3
+    assert got["slowest_step"] == {k: v for k, v in rec(4, 2.3, 1, 0).items()
+                                   if k != "loss"}
 
 
 # --- refusal without a chip -------------------------------------------------------------

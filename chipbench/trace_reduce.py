@@ -1,7 +1,8 @@
 """Reduction of a profiler trace to the numbers the per-layer metrics read.
 
-``load`` reads the ``.xplane.pb`` that ``jax.profiler`` writes and keeps
-three things, on one clock in nanoseconds:
+A reduced trace (``program_trace.load`` makes one from the ``.xplane.pb``
+that ``jax.profiler`` writes, through ``reduced`` here) keeps three
+things, on one clock in nanoseconds:
 
 - ``devices``: for each accelerator, every XLA operation that ran on it
   (line ``XLA Ops`` of a ``/device:<kind>:<n>`` plane) as (name, start,
@@ -47,25 +48,6 @@ def op_name(event_name: str) -> str:
 
 def is_collective(op_name: str) -> bool:
     return bool(_COLLECTIVE.search(op_name))
-
-
-def load(xplane_path: str) -> Dict:
-    from jax.profiler import ProfileData
-    pd = ProfileData.from_file(str(xplane_path))
-    devices: Dict[str, List] = {}
-    spans: List = []
-    for plane in pd.planes:
-        m = _DEVICE_PLANE.match(plane.name)
-        for line in plane.lines:
-            if m and line.name == OPS_LINE:
-                devices.setdefault(f"{m.group(1)}:{m.group(2)}", []).extend(
-                    [op_name(e.name), e.start_ns, e.duration_ns]
-                    for e in line.events)
-            elif plane.name.startswith("/host:"):
-                spans.extend([e.name, e.start_ns, e.duration_ns]
-                             for e in line.events
-                             if e.name.startswith(SPAN_PREFIX))
-    return reduced(devices, spans)
 
 
 def leaf_events(events: List) -> List:
@@ -188,22 +170,3 @@ def top_ops(red: Dict, n: int = 10) -> List[List]:
     k = max(len(red["devices"]), 1)
     return [[name, t / k] for name, t in
             sorted(tot.items(), key=lambda kv: -kv[1])[:n]]
-
-
-def idle_gaps(red: Dict, n: int = 10) -> List[List]:
-    """The longest stretches in which a device ran nothing, as [the
-    innermost host span around the gap's middle, seconds]."""
-    lo, hi = red["window"]
-    gaps = []
-    for dev in red["devices"]:
-        busy = clip([(s, e) for _, s, e in _ops(red, dev)], lo, hi)
-        gaps += subtract([(lo, hi)], busy)
-    named = []
-    for s, e in sorted(gaps, key=lambda g: g[0] - g[1])[:n]:
-        mid, inner = (s + e) / 2, None
-        for name, ss, dd in red["spans"]:
-            if ss <= mid <= ss + dd and (inner is None or dd < inner[1]):
-                inner = (name, dd)
-        named.append([inner[0] if inner else "outside the benchmark's spans",
-                      (e - s) * 1e-9])
-    return named
